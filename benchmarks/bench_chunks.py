@@ -149,7 +149,7 @@ def _rss_run(mode: str) -> Dict:
     with tempfile.TemporaryDirectory() as tmp:
         env = dict(os.environ)
         env["PYTHONPATH"] = "src:" + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"  # the child never takes the chip
         out = subprocess.run(
             [sys.executable, "-c", _RSS_SCRIPT, mode, str(STREAM_MB),
              str(WINDOW_MB), tmp],

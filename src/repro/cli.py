@@ -110,6 +110,7 @@ import sys
 
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.core import LineageGraph, bfs, module_diff
 from repro.store import ArtifactStore
 
@@ -296,8 +297,10 @@ def build_parser() -> argparse.ArgumentParser:
                    help="byte budget over the views' private (non-aliased) "
                         "bytes; the pinned base is not counted")
     p.add_argument("--backend", default=None,
+                   choices=("pallas", "interpret", "ref"),
                    help="kernel backend for delta application (default: "
-                        "host fold on CPU, fused chain_apply on device)")
+                        "the compiled Pallas kernels on a TPU, the host "
+                        "NumPy fold elsewhere)")
     p = sub.add_parser("train",
                        help="toy training run with continuous checkpointing "
                             "(DESIGN.md §15): every commit is an MGit "
@@ -331,6 +334,7 @@ def main(argv=None) -> int:
         print(dump_docs(ap))
         return 0
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.cmd == "obs":
         return _cmd_obs(args)
@@ -614,7 +618,7 @@ def _cmd_serve(args) -> int:
     from repro.serve import (HubLineageSource, LineageWatcher,
                              LocalLineageSource, ModelPool, Router, ServeApp,
                              make_server)
-    store = ArtifactStore(root=args.repo)
+    store = ArtifactStore(root=args.repo, backend=args.backend)
     pool = ModelPool(store, max_resident=args.max_resident,
                      budget_bytes=(args.budget_mb * (1 << 20)
                                    if args.budget_mb else None),

@@ -55,7 +55,9 @@ class SyntheticPipeline:
                 0, cfg.vocab_size, (self.batch, self.seq), dtype=np.int32)
         return out
 
-    def _place(self, batch: Dict[str, np.ndarray]) -> Dict[str, Any]:
+    def place(self, batch: Dict[str, np.ndarray]) -> Dict[str, Any]:
+        """Put a host batch on the mesh (batch dim over the data axes);
+        without a mesh the jitted step takes the host arrays as they are."""
         if self.mesh is None:
             return batch
         placed = {}
@@ -69,7 +71,7 @@ class SyntheticPipeline:
         return self
 
     def __next__(self) -> Dict[str, Any]:
-        b = self._place(self.host_batch(self.step))
+        b = self.place(self.host_batch(self.step))
         self.step += 1
         return b
 

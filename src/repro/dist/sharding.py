@@ -104,6 +104,16 @@ def param_spec(path: str, ndim: int) -> P:
     return P(*([None] * (ndim - 2)), "data", "model")
 
 
+def state_shardings(mesh: Mesh, state: Any) -> Any:
+    """A ``NamedSharding`` per leaf of a train-state pytree (arrays or
+    shape structs), placed by :func:`param_spec` of the leaf's
+    "/"-joined path — optimizer moments follow their parameter's rule."""
+    def one(path, leaf):
+        key = jax.tree_util.keystr(path, simple=True, separator="/")
+        return NamedSharding(mesh, param_spec(key, len(leaf.shape)))
+    return jax.tree_util.tree_map_with_path(one, state)
+
+
 def shard_cuts(path: str, shape, itemsize: int,
                n_shards: int) -> Optional[list]:
     """Byte offsets where ``n_shards`` axis-0 shards of this param begin/end.

@@ -25,6 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -102,13 +103,9 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     kernel = functools.partial(
         _flash_kernel, qc=qc, kc=kc, n_k=n_k, causal=causal, window=window,
         prefix_len=prefix_len, scale=hd ** -0.5)
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        scratch = [pltpu.VMEM((qc,), jnp.float32),
-                   pltpu.VMEM((qc,), jnp.float32),
-                   pltpu.VMEM((qc, hd), jnp.float32)]
-    except ImportError:  # pragma: no cover
-        scratch = [pl.VMEM((qc,), jnp.float32)]
+    scratch = [pltpu.VMEM((qc,), jnp.float32),
+               pltpu.VMEM((qc,), jnp.float32),
+               pltpu.VMEM((qc, hd), jnp.float32)]
     return pl.pallas_call(
         kernel,
         grid=grid,

@@ -1,70 +1,143 @@
-"""Jit'd public wrappers for the storage-path kernels.
+"""Public wrappers for the storage-path kernels.
+
+Backends: ``"pallas"`` runs the compiled Pallas kernels (the default on a
+TPU), ``"interpret"`` runs the same kernels in the Pallas interpreter (tests
+on CPU), and ``"ref"`` runs the jnp oracles of ``ref.py`` (the default
+elsewhere — same semantics, no interpreter overhead).
 
 Canonicalization: every tensor is flattened and zero-padded to a
-(rows, LANE_COLS) layout with rows a multiple of 8 (TPU sublane), then
-dispatched to the Pallas kernel (TPU), the interpret-mode kernel (tests), or
-the pure-jnp oracle (CPU hosts — same semantics, no interpreter overhead).
-Results are cropped back to the original shape, and zero counts are corrected
-for padding, so callers never see the canonical layout.
+(rows, LANE_COLS) layout whose rows are a whole number of row blocks, each a
+multiple of 32 rows (the int8 tile), dispatched to the kernel, and cropped
+back to the original shape; zero counts are corrected for padding and the
+fingerprint masks it, so callers never see the canonical layout. Per kernel,
+one jitted device program per (shape, dtype) fuses the pad, the kernel, the
+crop and the reduction of the per-tile partials; the wrapper then reads its
+scalars back in one transfer.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+import math
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref as _ref
+from repro.kernels.chain_apply import chain_apply_2d, chain_apply_ref
 from repro.kernels.delta_quantize import (BLOCK_ROWS, LANE_COLS,
+                                          MIN_BLOCK_ROWS, PARTIAL,
                                           delta_quantize_2d, dequant_apply_2d)
 from repro.kernels.fingerprint import fingerprint_2d
+from repro.kernels.snapshot_fused import snapshot_fused_2d, snapshot_fused_ref
+from repro.obs import REGISTRY
+
+#: Pallas dispatches per kernel (compiled or interpreted; the jnp oracles
+#: are not counted), scrapeable as mgit_kernel_dispatches_*.
+DISPATCHES = REGISTRY.group(
+    "mgit_kernel_dispatches",
+    keys=("delta_quantize", "dequant_apply", "chain_apply", "snapshot_fused",
+          "fingerprint"),
+    help="Pallas storage-kernel dispatches, by kernel")
 
 
 def default_backend() -> str:
+    """The backend a ``backend=None`` caller gets: the compiled kernels on
+    a TPU, the jnp oracles anywhere else. Asking starts JAX's backend, so
+    host-only callers resolve it only when a kernel is actually needed."""
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
-def _pad_rows(n_flat: int, cols: int) -> int:
-    rows = -(-n_flat // cols)
-    return -(-rows // 8) * 8  # sublane multiple
+def _layout(n: int) -> Tuple[int, int]:
+    """(rows, block_rows) of the canonical layout holding ``n`` elements."""
+    rows = -(-max(n, 1) // LANE_COLS)
+    rows = -(-rows // MIN_BLOCK_ROWS) * MIN_BLOCK_ROWS
+    block = min(BLOCK_ROWS, rows)
+    return -(-rows // block) * block, block
 
 
-def _block_rows(rows: int) -> int:
-    for candidate in (BLOCK_ROWS, 128, 64, 32, 16, 8):
-        if rows % candidate == 0:
-            return candidate
-    return rows
-
-
-def _to_2d(x: jnp.ndarray, cols: int = LANE_COLS) -> Tuple[jnp.ndarray, int]:
-    """Flatten + zero-pad to (rows, cols); returns (array2d, n_real_elements)."""
+def _canon(x: jnp.ndarray, rows: int) -> jnp.ndarray:
     flat = jnp.ravel(x)
-    n = flat.shape[0]
-    rows = _pad_rows(n, cols)
-    flat = jnp.pad(flat, (0, rows * cols - n))
-    return flat.reshape(rows, cols), n
+    flat = jnp.pad(flat, (0, rows * LANE_COLS - flat.shape[0]))
+    return flat.reshape(rows, LANE_COLS)
 
 
-def _bits_2d(x: jnp.ndarray, cols: int = LANE_COLS) -> Tuple[jnp.ndarray, int]:
-    """Canonical uint32 bit view, padded to (rows, cols)."""
-    flat = jnp.ravel(x)
-    if flat.dtype == jnp.float32:
-        bits = jax.lax.bitcast_convert_type(flat, jnp.uint32)
-    elif flat.dtype in (jnp.bfloat16, jnp.float16):
-        bits = jax.lax.bitcast_convert_type(flat, jnp.uint16).astype(jnp.uint32)
-    elif flat.dtype == jnp.uint32:
-        bits = flat
-    elif flat.dtype == jnp.int32:
-        bits = jax.lax.bitcast_convert_type(flat, jnp.uint32)
-    else:
-        bits = jax.lax.bitcast_convert_type(flat.astype(jnp.float32), jnp.uint32)
-    n = bits.shape[0]
-    rows = _pad_rows(n, cols)
-    bits = jnp.pad(bits, (0, rows * cols - n))
-    return bits.reshape(rows, cols), n
+def _crop(x2d: jnp.ndarray, shape) -> jnp.ndarray:
+    return x2d.reshape(-1)[:math.prod(shape)].reshape(shape)
+
+
+#: The runtime zero the rounding kernels take (see ``delta_quantize``): a
+#: jit argument, so that no compiler, XLA's included, can fold it away.
+_ZERO = np.zeros((1,), np.int32)
+
+
+def _count(kernel: str, backend: str) -> bool:
+    """Count one kernel dispatch; True when it runs in the interpreter."""
+    DISPATCHES[kernel] += 1
+    return backend == "interpret"
+
+
+# ---------------------------------------------------------------------------
+# device programs: one jit per (shape, dtype, eps, backend)
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
+def _delta_quantize_dev(zero, p1, p2, *, eps, interpret):
+    rows, block = _layout(p1.size)
+    q, zeros = delta_quantize_2d(
+        zero, _canon(p1.astype(jnp.float32), rows),
+        _canon(p2.astype(jnp.float32), rows),
+        eps=eps, block_rows=block, interpret=interpret)
+    n_pad = rows * LANE_COLS - p1.size  # padded zeros quantize to 0
+    per_block = jnp.sum(zeros.reshape(-1, PARTIAL[0] * PARTIAL[1]), axis=1)
+    return _crop(q, p1.shape), jnp.sum(zeros) - n_pad, per_block
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "out_dtype", "interpret"))
+def _dequant_apply_dev(zero, p1, q, *, eps, out_dtype, interpret):
+    rows, block = _layout(p1.size)
+    if q.dtype != jnp.int8:
+        q = q.astype(jnp.int32)
+    out = dequant_apply_2d(zero, _canon(p1.astype(jnp.float32), rows),
+                           _canon(q, rows), eps=eps, block_rows=block,
+                           interpret=interpret)
+    return _crop(out, p1.shape).astype(out_dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "out_dtype", "interpret"))
+def _chain_apply_dev(zero, base, qs, *, eps, out_dtype, interpret):
+    rows, block = _layout(base.size)
+    # narrowed int8 hops stream at one byte each; any wide hop widens all
+    qdt = (jnp.int8 if all(q.dtype == jnp.int8 for q in qs) else jnp.int32)
+    stack = jnp.stack([_canon(q.astype(qdt), rows) for q in qs])
+    out = chain_apply_2d(zero, _canon(base.astype(jnp.float32), rows), stack,
+                         eps=eps, block_rows=block, interpret=interpret)
+    return _crop(out, base.shape).astype(out_dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
+def _snapshot_dev(zero, p1, p2, *, eps, interpret):
+    rows, block = _layout(p1.size)
+    q8, zeros, ovf, h1, h2 = snapshot_fused_2d(
+        zero, _canon(p1.astype(jnp.float32), rows),
+        _canon(p2.astype(jnp.float32), rows), n=p1.size,
+        eps=eps, block_rows=block, interpret=interpret)
+    n_pad = rows * LANE_COLS - p1.size
+    fp = jnp.stack([jnp.sum(h1, dtype=jnp.uint32),
+                    jnp.sum(h2, dtype=jnp.uint32)])
+    return _crop(q8, p1.shape), jnp.sum(zeros) - n_pad, jnp.sum(ovf), fp
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _fingerprint_dev(x, *, interpret):
+    rows, block = _layout(x.size)
+    return fingerprint_2d(_canon(_ref.fingerprint_bits(x), rows), n=x.size,
+                          block_rows=block, interpret=interpret)
+
+
+_fingerprint_ref = jax.jit(_ref.fingerprint_ref)
 
 
 # ---------------------------------------------------------------------------
@@ -76,29 +149,21 @@ def delta_quantize(p1, p2, eps: float = 1e-4, backend: Optional[str] = None,
     """Quantized delta q = floor((p1-p2)/scale + 0.5) (paper Algorithm 1).
 
     Returns (q int32 array shaped like p1, n_zero int) — optionally also the
-    per-tile zero counts used by the compressibility pre-filter.
+    per-row-block zero counts used by the compressibility pre-filter.
     """
     backend = backend or default_backend()
     p1 = jnp.asarray(p1)
     p2 = jnp.asarray(p2)
-    orig_shape = p1.shape
     if backend == "ref":
         q, nz = _ref.delta_quantize_ref(p1, p2, eps)
         if return_block_zeros:
             return q, int(nz), None
         return q, int(nz)
-
-    a, n = _to_2d(p1)
-    b, _ = _to_2d(p2)
-    q2d, block_zeros = delta_quantize_2d(a, b, eps=eps,
-                                         block_rows=_block_rows(a.shape[0]),
-                                         interpret=(backend == "interpret"))
-    q = q2d.reshape(-1)[:n].reshape(orig_shape)
-    n_pad = a.size - n  # padded elements are exact zeros and were counted
-    nz = int(jnp.sum(block_zeros)) - n_pad
+    q, nz, per_block = _delta_quantize_dev(
+        _ZERO, p1, p2, eps=eps, interpret=_count("delta_quantize", backend))
     if return_block_zeros:
-        return q, nz, np.asarray(block_zeros)
-    return q, nz
+        return q, int(nz), np.asarray(per_block)
+    return q, int(nz)
 
 
 def dequant_apply(p1, q, eps: float = 1e-4, out_dtype=None,
@@ -106,19 +171,16 @@ def dequant_apply(p1, q, eps: float = 1e-4, out_dtype=None,
     """Reconstruct the child parameter: p2' = p1 - q*scale."""
     backend = backend or default_backend()
     p1 = jnp.asarray(p1)
-    q = jnp.asarray(q, dtype=jnp.int32)
+    q = jnp.asarray(q)
     if backend == "ref":
-        return _ref.dequant_apply_ref(p1, q, eps, out_dtype=out_dtype)
-    orig_shape = p1.shape
-    a, n = _to_2d(p1)
-    qq, _ = _to_2d(q)
-    out2d = dequant_apply_2d(a, qq, eps=eps, block_rows=_block_rows(a.shape[0]),
-                             interpret=(backend == "interpret"))
-    out = out2d.reshape(-1)[:n].reshape(orig_shape)
-    return out.astype(out_dtype or p1.dtype)
+        return _ref.dequant_apply_ref(p1, q.astype(jnp.int32), eps,
+                                      out_dtype=out_dtype)
+    return _dequant_apply_dev(
+        _ZERO, p1, q, eps=eps, out_dtype=np.dtype(out_dtype or p1.dtype),
+        interpret=_count("dequant_apply", backend))
 
 
-def chain_apply(base, qs, eps: float = 1e-4, out_dtype=None,
+def chain_apply(base, qs: Sequence, eps: float = 1e-4, out_dtype=None,
                 backend: Optional[str] = None):
     """Fused delta-chain application: ``base - sum(qs) * scale`` (§10.2).
 
@@ -129,34 +191,18 @@ def chain_apply(base, qs, eps: float = 1e-4, out_dtype=None,
     correctly-rounded f32 op either way."""
     backend = backend or default_backend()
     base = jnp.asarray(base)
-    stack = jnp.stack([jnp.asarray(q, dtype=jnp.int32).reshape(base.shape)
-                       for q in qs])
+    qs = tuple(jnp.asarray(q).reshape(base.shape) for q in qs)
+    out_dtype = np.dtype(out_dtype or base.dtype)
     if backend == "ref":
-        from repro.kernels.chain_apply import chain_apply_ref
-        out = chain_apply_ref(base, stack, eps)
-        return out.astype(out_dtype or base.dtype)
-    from repro.kernels.chain_apply import chain_apply_2d
-    orig_shape = base.shape
-    a, n = _to_2d(base.astype(jnp.float32))
-    # pad each q independently to the canonical layout (zero padding is
-    # exact: padded lanes contribute 0 to the int32 sum)
-    q2d = jnp.stack([_to_2d(stack[i])[0].astype(jnp.int32)
-                     for i in range(stack.shape[0])])
-    out2d = chain_apply_2d(a, q2d, eps=eps,
-                           block_rows=_block_rows(a.shape[0]),
-                           interpret=(backend == "interpret"))
-    out = out2d.reshape(-1)[:n].reshape(orig_shape)
-    return out.astype(out_dtype or base.dtype)
+        out = chain_apply_ref(base, jnp.stack(qs).astype(jnp.int32), eps)
+        return out.astype(out_dtype)
+    return _chain_apply_dev(_ZERO, base, qs, eps=eps, out_dtype=out_dtype,
+                            interpret=_count("chain_apply", backend))
 
 
 # ---------------------------------------------------------------------------
-# fingerprint
+# fused snapshot + fingerprint
 # ---------------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=())
-def _fingerprint_ref_2d(bits: jnp.ndarray) -> jnp.ndarray:
-    return _ref.fingerprint_ref(bits)
-
 
 def snapshot_fused(p1, p2, eps: float = 1e-4, backend: Optional[str] = None,
                    with_fingerprint: bool = True):
@@ -172,47 +218,50 @@ def snapshot_fused(p1, p2, eps: float = 1e-4, backend: Optional[str] = None,
     backend = backend or default_backend()
     p1 = jnp.asarray(p1)
     p2 = jnp.asarray(p2)
-    orig_shape = p1.shape
-    fp = fingerprint(p2, backend=backend) if with_fingerprint else None
     if backend == "ref":
-        from repro.kernels.snapshot_fused import snapshot_fused_ref
+        fp = fingerprint(p2, backend=backend) if with_fingerprint else None
         q8, zeros, overflow = snapshot_fused_ref(jnp.ravel(p1), jnp.ravel(p2),
                                                  eps)
         if int(overflow) > 0:
             q, nz = delta_quantize(p1, p2, eps=eps, backend=backend)
             return q, nz, fp, False
-        return (jnp.asarray(q8).reshape(orig_shape), int(zeros), fp, True)
+        return jnp.asarray(q8).reshape(p1.shape), int(zeros), fp, True
 
-    from repro.kernels.snapshot_fused import snapshot_fused_2d
-    a, n = _to_2d(p1.astype(jnp.float32))
-    b, _ = _to_2d(p2.astype(jnp.float32))
-    q2d, zeros, overflow, _fp_part = snapshot_fused_2d(
-        a, b, eps=eps, block_rows=_block_rows(a.shape[0]),
-        interpret=(backend == "interpret"))
-    if int(jnp.sum(overflow)) > 0:
+    q, nz, ovf, pair = _snapshot_dev(
+        _ZERO, p1, p2, eps=eps, interpret=_count("snapshot_fused", backend))
+    nz, ovf, pair = jax.device_get((nz, ovf, pair))
+    fp = None
+    if with_fingerprint:
+        # the fused pass hashes p2's float32 bits: that IS fingerprint(p2)
+        # for float32 tensors; other dtypes hash their own bit width
+        fp = (fold_fingerprint(p2, pair) if p2.dtype == jnp.float32
+              else fingerprint(p2, backend=backend))
+    if int(ovf) > 0:
         q, nz = delta_quantize(p1, p2, eps=eps, backend=backend)
         return q, nz, fp, False
-    q = q2d.reshape(-1)[:n].reshape(orig_shape)
-    n_pad = a.size - n
-    nz = int(jnp.sum(zeros)) - n_pad
-    return q, nz, fp, True
+    return q, int(nz), fp, True
 
 
-def fingerprint(x, backend: Optional[str] = None) -> int:
-    """64-bit content fingerprint (python int). Includes shape/dtype salt so
-    reshaped or recast tensors don't alias (mirrors SHA-256 keying in the CAS)."""
-    backend = backend or default_backend()
-    x = jnp.asarray(x)
-    bits, _ = _bits_2d(x)
-    if backend == "ref":
-        pair = _fingerprint_ref_2d(bits)
-    else:
-        pair = fingerprint_2d(bits, block_rows=_block_rows(bits.shape[0]),
-                              interpret=(backend == "interpret"))
-    h1, h2 = int(pair[0]), int(pair[1])
-    salt = hash((x.shape, str(x.dtype))) & 0xFFFFFFFF
+def fold_fingerprint(x, pair) -> int:
+    """Fold an (h1, h2) pair — from a kernel, the jnp oracle or the NumPy
+    twin — into one int, salted with shape and dtype so reshaped or recast
+    tensors don't alias (mirrors SHA-256 keying in the CAS)."""
+    h1, h2 = (int(v) for v in np.asarray(pair))
+    salt = hash((tuple(x.shape), str(x.dtype))) & 0xFFFFFFFF
     return ((h1 ^ salt) << 32) | h2
 
 
-__all__ = ["delta_quantize", "dequant_apply", "chain_apply", "fingerprint",
-           "default_backend"]
+def fingerprint(x, backend: Optional[str] = None) -> int:
+    """64-bit content fingerprint (python int) of ``x``'s values, shape and
+    dtype."""
+    backend = backend or default_backend()
+    x = jnp.asarray(x)
+    if backend == "ref":
+        return fold_fingerprint(x, _fingerprint_ref(x))
+    return fold_fingerprint(x, _fingerprint_dev(
+        x, interpret=_count("fingerprint", backend)))
+
+
+__all__ = ["delta_quantize", "dequant_apply", "chain_apply", "snapshot_fused",
+           "fingerprint", "fold_fingerprint", "default_backend",
+           "DISPATCHES"]

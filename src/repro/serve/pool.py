@@ -11,7 +11,8 @@ does. The pool:
 * derives each served node's ``ResidentView`` by applying its folded
   per-segment deltas directly over the resident base arrays — fused
   ``ops.chain_apply`` on device backends, int32 segment sum + one host
-  dequant per segment on CPU (bit-identical, DESIGN.md §10.2);
+  dequant per segment on the ``ref`` backend (bit-identical, DESIGN.md
+  §10.2);
 * aliases every parameter whose content hash matches a base parameter
   (the common case for sparse finetunes: unchanged tensors cost zero
   bytes per derivative);
@@ -114,9 +115,10 @@ class ResidentView:
 class ModelPool:
     """LRU pool of :class:`ResidentView`\\ s over one pinned chain base.
 
-    ``backend`` follows the kernels convention: ``None``/``"ref"`` apply
-    segments on the host (int32 sum + one dequant — bit-identical to the
-    fused kernel), anything else dispatches ``ops.chain_apply``.
+    ``backend`` follows the kernels convention: ``"ref"`` applies segments
+    on the host (int32 sum + one dequant — bit-identical to the fused
+    kernel), anything else dispatches ``ops.chain_apply``; ``None`` means
+    ``ops.default_backend()``, asked when a kernel is first needed.
     ``verify=False`` skips the per-param truth-hash assertion (benchmarks
     measuring raw build latency); serving keeps it on.
     """
@@ -290,26 +292,41 @@ class ModelPool:
                 if open_qs:
                     value = self._apply_segment(value, open_qs, open_eps)
                     open_qs = []
-                value = host_dequant(value, q, hop.eps,
-                                     out_dtype=hop.dtype).reshape(hop.shape)
+                value = self._dequant(value, q, hop.eps,
+                                      hop.dtype).reshape(hop.shape)
         if open_qs:
             value = self._apply_segment(value, open_qs, open_eps)
         return np.asarray(value).reshape(hops[-1].shape) if hops \
             else np.asarray(value)
 
+    def _kernel_backend(self) -> str:
+        from repro.kernels import ops
+        return self.backend or ops.default_backend()
+
+    def _dequant(self, value: np.ndarray, q: np.ndarray, eps: float,
+                 out_dtype: str) -> np.ndarray:
+        backend = self._kernel_backend()
+        if backend == "ref":
+            return host_dequant(value, q, eps, out_dtype=out_dtype)
+        from repro.kernels import ops
+        return np.asarray(ops.dequant_apply(np.asarray(value), q, eps=eps,
+                                            backend=backend,
+                                            out_dtype=out_dtype))
+
     def _apply_segment(self, value: np.ndarray, qs: List[np.ndarray],
                        eps: float) -> np.ndarray:
         self._count(segments_applied=1)
-        if self.backend not in (None, "ref") and len(qs) > 1:
+        backend = self._kernel_backend()
+        if backend != "ref" and len(qs) > 1:
             from repro.kernels import ops
             self._count(fused_applies=1)
             return np.asarray(ops.chain_apply(
-                np.asarray(value), qs, eps=eps, backend=self.backend,
+                np.asarray(value), qs, eps=eps, backend=backend,
                 out_dtype="float32"))
         acc = qs[0] if qs[0].dtype == np.int32 else qs[0].astype(np.int32)
         for q in qs[1:]:
             acc = np.add(acc, q.reshape(acc.shape), dtype=np.int32)
-        return host_dequant(value, acc, eps, out_dtype="float32")
+        return self._dequant(value, acc, eps, "float32")
 
     # -- bookkeeping ---------------------------------------------------------
     def _count(self, **deltas: int) -> None:

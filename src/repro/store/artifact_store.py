@@ -345,6 +345,16 @@ class ArtifactStore:
                 f"{'pipelined=True (default)' if recorded == 'fold' else 'pipelined=False'} "
                 f"(DESIGN.md §10.2: one truth definition per repository)")
 
+    def _kernel_backend(self) -> str:
+        """``backend``, else what ``ops.default_backend()`` says: the
+        compiled Pallas kernels on a TPU, the NumPy twins elsewhere.
+
+        Asked at each kernel use and never in ``__init__``: the hub, replica
+        and GC processes build stores but run no kernel, and must not start
+        a JAX backend (it would take the chip from a server on the host)."""
+        from repro.kernels import ops
+        return self.backend or ops.default_backend()
+
     def _executor(self) -> ThreadPoolExecutor:
         """Shared worker pool for commit encode + batched checkout decode.
 
@@ -406,7 +416,7 @@ class ArtifactStore:
                         codec=self.codec, tests=tests,
                         per_param=self.per_param,
                         zero_frac_prefilter=self.zero_frac_prefilter,
-                        backend=self.backend)
+                        backend=self._kernel_backend())
                 self.last_result = commit_result = result
                 if result.accepted:
                     deltas = result.deltas
@@ -500,7 +510,8 @@ class ArtifactStore:
         pvals = self.materialize_artifact(
             parent_ref, keys=[pk for pk, _ in pairs]).params
 
-        host = self.backend in (None, "ref")
+        backend = self._kernel_backend()
+        host = backend == "ref"
 
         def process(pair):
             pkey, ckey = pair
@@ -513,7 +524,7 @@ class ArtifactStore:
                     q, nz, _narrow = host_snapshot(p1, p2, self.eps)
                 else:
                     q, nz, _fp, _narrow = ops.snapshot_fused(
-                        p1, p2, eps=self.eps, backend=self.backend,
+                        p1, p2, eps=self.eps, backend=backend,
                         with_fingerprint=False)
                     q = np.asarray(q)
             if nz / q.size < self.zero_frac_prefilter:
@@ -597,14 +608,15 @@ class ArtifactStore:
         ``_is_segment_boundary``."""
         if eps is None:
             eps = self.eps
-        if self.backend in (None, "ref"):
+        backend = self._kernel_backend()
+        if backend == "ref":
             dequant = host_dequant
         else:
             from repro.kernels import ops
 
             def dequant(v, q, e_, out_dtype="float32"):
                 return np.asarray(ops.dequant_apply(
-                    np.asarray(v), q, eps=e_, backend=self.backend,
+                    np.asarray(v), q, eps=e_, backend=backend,
                     out_dtype=out_dtype))
 
         if dtype == "float32" and self.fold_enabled:
@@ -1391,12 +1403,13 @@ class ArtifactStore:
         baseline (``pipelined=False``) keeps the original per-hop jax
         dispatch so benchmarks measure the pre-pipeline engine faithfully.
         Device backends always dispatch."""
-        if self.pipelined and self.backend in (None, "ref"):
+        backend = self._kernel_backend()
+        if self.pipelined and backend == "ref":
             out = host_dequant(value, q, eps, out_dtype=out_dtype)
         else:
             from repro.kernels import ops
             out = np.asarray(ops.dequant_apply(
-                np.asarray(value), q, eps=eps, backend=self.backend,
+                np.asarray(value), q, eps=eps, backend=backend,
                 out_dtype=out_dtype))
         with self._lock:
             self.io_stats["dequant_calls"] += 1
@@ -1422,10 +1435,11 @@ class ArtifactStore:
         reduction in VMEM) — bit-identical to host sum + dequant. Returns
         ``(value, qsum)``; the sum is only computed when the caller needs
         it for a FoldState (``need_sum``) or the host path uses it."""
-        if len(open_qs) > 1 and self.backend not in (None, "ref"):
+        backend = self._kernel_backend()
+        if len(open_qs) > 1 and backend != "ref":
             from repro.kernels import ops
             out = np.asarray(ops.chain_apply(
-                np.asarray(value), open_qs, eps=eps, backend=self.backend,
+                np.asarray(value), open_qs, eps=eps, backend=backend,
                 out_dtype="float32"))
             with self._lock:
                 self.io_stats["dequant_calls"] += 1
@@ -1694,8 +1708,8 @@ class ArtifactStore:
                                codec=e["codec"], eps=e["eps"],
                                shape=tuple(e["shape"]), dtype=e["dtype"],
                                raw_bytes=0, qdtype=e.get("qdtype", "int32"))
-                params[key] = decompress_param(parent_val, d,
-                                               backend=self.backend)
+                params[key] = decompress_param(
+                    parent_val, d, backend=self._kernel_backend())
                 states[key] = None
         artifact = ModelArtifact(
             graph=LayerGraph.from_json(manifest["graph"]),

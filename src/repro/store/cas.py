@@ -78,6 +78,7 @@ import threading
 from collections import OrderedDict
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
+import ml_dtypes  # noqa: F401  (registers bfloat16 & co. by name with NumPy)
 import numpy as np
 
 from repro.common.faults import kill_point
@@ -554,7 +555,17 @@ class CAS:
                 self.refcounts[key] = self.refcounts.get(key, 0) + 1
             return key
         buf = io.BytesIO()
-        np.save(buf, arr, allow_pickle=False)
+        if arr.dtype.kind == "V" and arr.dtype.fields is None:
+            # ml_dtypes (bfloat16, float8_*): np.save would write the bare
+            # '|V2' descr and the tensor would read back as raw void bytes;
+            # the dtype's name reads back as itself wherever ml_dtypes is
+            # loaded (JAX loads it)
+            np.lib.format.write_array_header_1_0(
+                buf, {"descr": arr.dtype.name, "fortran_order": False,
+                      "shape": arr.shape})
+            buf.write(np.ascontiguousarray(arr).tobytes())
+        else:
+            np.save(buf, arr, allow_pickle=False)
         return self.put_bytes(buf.getvalue(), key=key)
 
     def get_tensor(self, key: str) -> np.ndarray:

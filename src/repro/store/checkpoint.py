@@ -9,9 +9,10 @@ stores them as deltas against the previous step's committed truth.
 The manager layers four things over the store engine:
 
 * **fingerprint short-circuit** — leaves above ``fingerprint_min_bytes``
-  are fingerprinted before transfer (device-side via the fused kernel on
-  accelerators — 8 bytes cross the link instead of the tensor — or a
-  host CRC pair on CPU). A leaf whose fingerprint matches the last
+  are fingerprinted before transfer (device-side via the fingerprint
+  kernel when ``ops.default_backend()`` is not ``ref`` — 8 bytes cross the
+  link instead of the tensor; sharded leaves shard by shard — or a host
+  CRC pair otherwise). A leaf whose fingerprint matches the last
   enqueued snapshot is *skipped*: no host copy, no encode, its manifest
   entry re-references the parent's.
 * **tiers** — ``tier="exact"`` (default) stores lossless bitpattern
@@ -86,20 +87,8 @@ CKPT_STATS = REGISTRY.group(
 
 
 def _keystr(path) -> str:
-    """``jax.tree_util.keystr(path, simple=True, separator="/")`` compat.
-
-    The ``simple``/``separator`` kwargs only exist on newer JAX; render the
-    key path entries directly so any 0.4.x works."""
-    parts = []
-    for entry in path:
-        for attr in ("key", "name", "idx"):
-            v = getattr(entry, attr, None)
-            if v is not None:
-                parts.append(str(v))
-                break
-        else:
-            parts.append(str(entry).strip("[].'\""))
-    return "/".join(parts)
+    """A leaf's key in a checkpoint: its pytree path joined with "/"."""
+    return jax.tree_util.keystr(path, simple=True, separator="/")
 
 
 def flatten_state(state) -> Dict[str, np.ndarray]:
@@ -259,7 +248,23 @@ class CheckpointManager:
     def _use_device_fp(self) -> bool:
         if self.fingerprint_device is not None:
             return self.fingerprint_device
-        return jax.default_backend() != "cpu"
+        from repro.kernels import ops
+        return ops.default_backend() != "ref"
+
+    @staticmethod
+    def _device_fp(leaf) -> int:
+        """Device fingerprint of one leaf. A sharded leaf is hashed shard by
+        shard: GSPMD cannot partition a ``pallas_call``, and gathering would
+        copy the whole leaf onto one chip. Replicas are hashed once."""
+        from repro.kernels import ops
+        shards = [s for s in getattr(leaf, "addressable_shards", ())
+                  if s.replica_id == 0]
+        if not shards:
+            return ops.fingerprint(leaf)
+        if len(shards) == 1:
+            return ops.fingerprint(shards[0].data)
+        return hash(tuple((str(s.index), ops.fingerprint(s.data))
+                          for s in shards))
 
     @staticmethod
     def _host_fp(arr: np.ndarray) -> int:
@@ -291,8 +296,7 @@ class CheckpointManager:
                 flat[key] = np.asarray(jax.device_get(leaf))
                 continue
             if device_fp:
-                from repro.kernels import ops
-                fp = int(ops.fingerprint(leaf))
+                fp = self._device_fp(leaf)
                 fps[key] = fp
                 if self._last_fps.get(key) == fp:
                     flat[key] = None

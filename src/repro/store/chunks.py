@@ -20,9 +20,12 @@ Two properties matter for the layers above:
 
 The pure-python byte loop of classic FastCDC is far too slow for GB-scale
 tensors, so the rolling hash is vectorized: with window W=8 the Gear hash of
-position ``i`` is ``G0[b[i]] ^ G1[b[i-1]] ^ ... ^ G7[b[i-7]]`` — eight
-shifted table lookups XOR'd as numpy u64 arrays, processed in bounded
-sub-blocks so the temporaries never exceed a few MB.
+position ``i`` is ``G0[b[i]] ^ G1[b[i-1]] ^ ... ^ G7[b[i-7]]``. A cut test
+reads only the hash's low bits (the mask is below 2^32), so the scan keeps
+32 of its 64 bits and looks up two window bytes at a time in four
+65536-entry pair tables — four gathers per byte, over cache-sized
+sub-blocks. Each segment is scanned once; the greedy cut walk then only
+searches the sorted candidate positions.
 """
 
 from __future__ import annotations
@@ -42,7 +45,7 @@ DEFAULT_MAX_CHUNK = 4 * 2 ** 20
 DEFAULT_WINDOW_BYTES = 64 * 2 ** 20      # commit/checkout in-flight budget
 
 WINDOW = 8                               # rolling-hash window, bytes
-_SCAN_BLOCK = 4 * 2 ** 20                # sub-block for vectorized hashing
+_SCAN_BLOCK = 1 * 2 ** 20                # sub-block for vectorized hashing
 
 # 8 independent 256-entry u64 tables from a fixed-seed PRNG: boundary
 # positions are a pure function of content, stable across processes/versions.
@@ -50,63 +53,49 @@ _GEAR = np.random.default_rng(0x4D476974).integers(
     0, 2 ** 64, size=(WINDOW, 256), dtype=np.uint64)
 
 
-def _window_hash(block: np.ndarray) -> np.ndarray:
-    """Gear window hash for each position i >= WINDOW-1 of a u8 block."""
-    n = block.size
-    h = _GEAR[0][block[WINDOW - 1:]]
-    for j in range(1, WINDOW):
-        h ^= _GEAR[j][block[WINDOW - 1 - j:n - j]]
-    return h
+def _pair_tables() -> List[np.ndarray]:
+    """Table k maps the byte pair ``b[i-2k] | b[i-2k-1] << 8`` to
+    ``G(2k)[b[i-2k]] ^ G(2k+1)[b[i-2k-1]]`` (low 32 bits)."""
+    g = (_GEAR & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+    pair = np.arange(1 << 16)
+    return [g[2 * k][pair & 0xFF] ^ g[2 * k + 1][pair >> 8]
+            for k in range(WINDOW // 2)]
 
 
-def _candidates(data: memoryview, mask: int) -> np.ndarray:
-    """Positions p where the windowed hash over bytes [p-7, p] hits the mask.
+_PAIRS = _pair_tables()
 
-    A cut at p means "chunk ends after byte p" (exclusive offset p+1).
-    Processes the buffer in sub-blocks with a WINDOW-1 byte overlap so the
-    u64 temporaries stay bounded regardless of input size.
-    """
-    buf = np.frombuffer(data, dtype=np.uint8)
-    n = buf.size
-    if n < WINDOW:
-        return np.empty(0, dtype=np.int64)
-    out: List[np.ndarray] = []
-    mask64 = np.uint64(mask)
-    start = 0
-    while start < n - WINDOW + 1:
-        stop = min(n, start + _SCAN_BLOCK)
-        block = buf[start:stop]
-        if block.size < WINDOW:
-            break
-        hits = np.flatnonzero((_window_hash(block) & mask64) == 0)
-        if hits.size:
-            out.append(hits.astype(np.int64) + start + WINDOW - 1)
-        start = stop - (WINDOW - 1)
-    if not out:
-        return np.empty(0, dtype=np.int64)
+
+def _block_candidates(block: np.ndarray, mask: np.uint32) -> np.ndarray:
+    """Offsets p >= WINDOW-1 in a u8 block whose Gear hash over bytes
+    [p-7, p] hits the mask."""
+    pair = block[1:].astype(np.uint16) | (block[:-1].astype(np.uint16) << 8)
+    n = block.size - (WINDOW - 1)        # positions WINDOW-1 .. size-1
+    top = WINDOW - 2                     # pair index of position WINDOW-1
+    h = np.take(_PAIRS[0], pair[top:top + n])
+    for k in range(1, WINDOW // 2):
+        h ^= np.take(_PAIRS[k], pair[top - 2 * k:top - 2 * k + n])
+    return np.flatnonzero((h & mask) == 0) + (WINDOW - 1)
+
+
+def _candidates(read: Callable[[int, int], bytes], start: int, length: int,
+                mask: int) -> np.ndarray:
+    """Offsets p (relative to ``start``) in a ``length``-byte stream where
+    the windowed hash over bytes [p-7, p] hits the mask, ascending.
+
+    A cut at p means "chunk ends after byte p" (exclusive offset p+1). The
+    stream is read in sub-blocks with a WINDOW-1 byte overlap so the
+    temporaries stay bounded regardless of input size."""
+    if mask >= 1 << 32:
+        raise ValueError(f"chunk hash mask {mask:#x} exceeds 32 bits")
+    mask32 = np.uint32(mask)
+    out: List[np.ndarray] = [np.empty(0, dtype=np.int64)]
+    off = 0
+    while off < length - (WINDOW - 1):
+        size = min(length - off, _SCAN_BLOCK + WINDOW - 1)
+        block = np.frombuffer(read(start + off, size), dtype=np.uint8)
+        out.append(_block_candidates(block, mask32).astype(np.int64) + off)
+        off += _SCAN_BLOCK
     return np.concatenate(out)
-
-
-def _next_cut(data, min_size: int, max_size: int, itemsize: int,
-              mask: int) -> int:
-    """Length of the next chunk given a ``max_size``-byte lookahead window.
-
-    FastCDC-style greedy selection: the first boundary candidate whose
-    snapped offset lands in [min_size, max_size], else a forced cut at
-    max_size. Offsets snap down to itemsize multiples so chunks hold whole
-    elements.
-    """
-    def snap(off: int) -> int:
-        return (off // itemsize) * itemsize
-
-    for c in _candidates(memoryview(data), mask):
-        cut = snap(int(c) + 1)
-        if cut < min_size:
-            continue
-        if cut > max_size:
-            break
-        return cut
-    return max(itemsize, snap(max_size))
 
 
 def cut_points(read: Callable[[int, int], bytes], length: int, itemsize: int,
@@ -135,21 +124,33 @@ def cut_points(read: Callable[[int, int], bytes], length: int, itemsize: int,
     bounds.append(length)
     bounds = sorted(set(bounds))
 
+    def snap(off: int) -> int:
+        return (off // itemsize) * itemsize
+
     cuts: List[int] = []
     for seg_start, seg_end in zip(bounds[:-1], bounds[1:]):
         seg_len = seg_end - seg_start
         pos = 0
-        # One lookahead window of at most max_size bytes per cut decision:
-        # boundary selection never needs to see past pos+max_size, so the
-        # stream is scanned in bounded pieces regardless of tensor size.
+        cands = None
         while seg_len - pos > max_size:
             if mode == "fixed":
                 # deterministic grid at the configured average size; the
                 # tail chunk absorbs the remainder (up to max_size)
                 cut = max(min_size, (avg_size // itemsize) * itemsize)
             else:
-                data = read(seg_start + pos, max_size)
-                cut = _next_cut(data, min_size, max_size, itemsize, mask)
+                # FastCDC-style greedy selection: the first candidate whose
+                # window lies past pos and whose snapped offset lands in
+                # [min_size, max_size], else a forced cut at max_size.
+                # Offsets snap down to itemsize multiples so chunks hold
+                # whole elements.
+                if cands is None:
+                    cands = _candidates(read, seg_start, seg_len, mask)
+                first = pos + max(min_size - 1, WINDOW - 1)
+                i = int(np.searchsorted(cands, first))
+                if i < cands.size and cands[i] < pos + max_size:
+                    cut = snap(int(cands[i]) - pos + 1)
+                else:
+                    cut = max(itemsize, snap(max_size))
             if seg_len - (pos + cut) < itemsize:
                 break
             pos += cut
@@ -180,7 +181,9 @@ class ArraySource:
 
     def __init__(self, arr: np.ndarray) -> None:
         self._arr = np.ascontiguousarray(arr)
-        self._mv = memoryview(self._arr).cast("B")
+        # a byte view: the buffer protocol has no format for ml_dtypes
+        # (bfloat16, float8_*), so the array itself cannot be cast
+        self._mv = memoryview(self._arr.reshape(-1).view(np.uint8))
         self.shape = tuple(int(d) for d in self._arr.shape)
         self.dtype = np.dtype(self._arr.dtype)
         self.nbytes = int(self._arr.nbytes)

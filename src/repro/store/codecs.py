@@ -149,7 +149,7 @@ class BytePlaneCodec(Codec):
         planes = a.view(np.uint8).reshape(-1, item).T
         return zlib.compress(np.ascontiguousarray(planes).tobytes(), self.level)
 
-    def decode(self, data: bytes, n: int, dtype: str = "uint32") -> np.ndarray:
+    def decode(self, data: bytes, n: int, dtype: str = "int32") -> np.ndarray:
         dt = np.dtype(dtype)
         planes = np.frombuffer(zlib.decompress(data), dtype=np.uint8)
         planes = planes.reshape(dt.itemsize, n)

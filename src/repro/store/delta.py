@@ -207,15 +207,17 @@ def host_snapshot(p1: np.ndarray, p2: np.ndarray, eps: float
     """Numpy twin of ``ops.snapshot_fused`` (sans fingerprint).
 
     Returns ``(q int8|int32, n_zero, narrow)``, bit-identical to the jax
-    ref kernel (both compute ``floor(f32(p1-p2)/f32(scale) + 0.5)`` with
-    correctly-rounded f32 ops; asserted in ``tests/test_pipeline.py``) but
-    with zero dispatch overhead — on CPU hosts the per-call jit dispatch
-    dominates the arithmetic for typical layer-sized tensors, so the commit
-    pipeline uses this path when no accelerator backend is configured."""
-    from repro.kernels.ref import quant_scale
-    scale = np.float32(quant_scale(eps))
+    ref kernel and the Pallas kernel (all compute
+    ``floor(f32(p1-p2) * inv_quant_scale(eps) + 0.5)`` with correctly-
+    rounded f32 ops; asserted in ``tests/test_pipeline.py`` and on the chip
+    by ``chip_smoke.py``) but with zero dispatch overhead — on CPU hosts
+    the per-call jit dispatch dominates the arithmetic for typical
+    layer-sized tensors, so the commit pipeline uses this path when the
+    kernel backend is ``ref``."""
+    from repro.kernels.ref import inv_quant_scale
     d = np.asarray(p1, dtype=np.float32) - np.asarray(p2, dtype=np.float32)
-    q32 = np.floor(d / scale + np.float32(0.5)).astype(np.int32)
+    q32 = np.floor(d * inv_quant_scale(eps)
+                   + np.float32(0.5)).astype(np.int32)
     nz = int((q32 == 0).sum())
     q8 = np.clip(q32, -127, 127)
     if bool((q32 == q8).all()):
@@ -232,22 +234,15 @@ def host_dequant(parent_value: np.ndarray, q: np.ndarray, eps: float,
     typing rounds the python-float scale to f32 exactly like the explicit
     ``np.float32`` here; ``tests/test_pipeline.py`` asserts the identity) —
     but with zero dispatch overhead, which is what the checkout/commit hot
-    loops need on CPU hosts. Non-f32 ``out_dtype`` casts go through jax
-    (ml_dtypes coverage, e.g. bf16) to keep rounding identical to the
-    device path."""
+    loops need on CPU hosts. Non-f32 ``out_dtype`` casts round to nearest
+    even through ml_dtypes (e.g. bf16), as XLA's convert does on the
+    device."""
     from repro.kernels.ref import quant_scale
     scale = np.float32(quant_scale(eps))
     out = (np.asarray(parent_value, dtype=np.float32)
            - np.asarray(q, dtype=np.float32) * scale)
     dt = np.dtype(out_dtype) if out_dtype is not None else np.float32
-    if dt == np.float32:
-        return out
-    try:
-        return out.astype(dt)
-    except TypeError:
-        return np.asarray(ops.dequant_apply(parent_value, q, eps=eps,
-                                            backend="ref",
-                                            out_dtype=out_dtype))
+    return out if dt == np.float32 else out.astype(dt)
 
 
 def decode_q(delta_or_entry, blob) -> np.ndarray:
@@ -266,9 +261,13 @@ def decode_q(delta_or_entry, blob) -> np.ndarray:
 
 def decompress_param(parent_value: np.ndarray, delta: ParamDelta,
                      backend: Optional[str] = None) -> np.ndarray:
-    """Invert one ParamDelta given the materialized parent tensor."""
+    """Invert one ParamDelta given the materialized parent tensor.
+
+    ``backend=None`` means ``ops.default_backend()``: the compiled kernel
+    on a TPU, the bit-identical NumPy twin elsewhere."""
     q = decode_q(delta, delta.blob)
-    if backend is None or backend == "ref":
+    backend = backend or ops.default_backend()
+    if backend == "ref":
         return host_dequant(parent_value, q, eps=delta.eps,
                             out_dtype=delta.dtype).reshape(delta.shape)
     out = ops.dequant_apply(np.asarray(parent_value), q, eps=delta.eps,

@@ -1,20 +1,24 @@
 """Fault-tolerant training loop: MGit-versioned checkpoints, restart, stragglers.
 
 The Trainer wires together the substrates: synthetic pipeline, jitted
-train_step (sharded when a mesh is given), CheckpointManager (every
-checkpoint is an MGit version node; restart resumes from the latest committed
-one, including onto a different mesh), and the straggler monitor.
+train_step, CheckpointManager (every checkpoint is an MGit version node;
+restart resumes from the latest committed one, including onto a different
+mesh), and the straggler monitor. Given a mesh, the state is created
+sharded per ``param_spec``, batches are placed per ``batch_spec``, and the
+step is traced under ``use_mesh`` so the model's ``shard`` constraints hold.
 """
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Callable, Dict, Optional
 
 import jax
-import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.data import SyntheticPipeline
+from repro.dist.sharding import state_shardings, use_mesh
 from repro.ft import ElasticRestart, StepTimer, StragglerPolicy
 from repro.models.config import ModelConfig
 from repro.optim import adamw
@@ -41,10 +45,19 @@ class Trainer:
         self.on_metrics = on_metrics
         self.pipeline = SyntheticPipeline(cfg, batch=batch, seq=seq, mesh=mesh,
                                           seed=seed)
-        self.train_step = jax.jit(make_train_step(
-            cfg, opt_cfg, n_microbatches=n_microbatches,
-            compress_grads=compress_grads), donate_argnums=(0,))
-        self.state = init_state(cfg, seed, compress_grads=compress_grads)
+        step_fn = make_train_step(cfg, opt_cfg, n_microbatches=n_microbatches,
+                                  compress_grads=compress_grads)
+        init = functools.partial(init_state, cfg, seed,
+                                 compress_grads=compress_grads)
+        if mesh is None:
+            self.train_step = jax.jit(step_fn, donate_argnums=(0,))
+            self.state = init()
+        else:
+            shardings = state_shardings(mesh, jax.eval_shape(init))
+            self.train_step = jax.jit(
+                step_fn, donate_argnums=(0,),
+                out_shardings=(shardings, NamedSharding(mesh, P())))
+            self.state = jax.jit(init, out_shardings=shardings)()
         self.timer = StepTimer()
         self.ckpt: Optional[CheckpointManager] = None
         self.start_step = 0
@@ -57,8 +70,16 @@ class Trainer:
             if latest is not None:  # crash restart: resume from last commit
                 # the lossy tier may resolve to the nearest exact ancestor,
                 # so resume from the step restore actually returned
-                self.state, restored = self.ckpt.restore(step=latest,
-                                                         template=self.state)
+                if mesh is None:
+                    self.state, restored = self.ckpt.restore(
+                        step=latest, template=self.state)
+                else:
+                    template = jax.tree_util.tree_map(
+                        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                       sharding=x.sharding),
+                        self.state)
+                    self.state, restored = self.ckpt.restore_sharded(
+                        template, step=latest)
                 self.start_step = restored
                 self.pipeline.step = restored
         # straggler escalation bottoms out in evict + elastic restart from
@@ -69,9 +90,10 @@ class Trainer:
     def run(self, n_steps: int) -> Dict[str, list]:
         history: Dict[str, list] = {"loss": [], "step_time": []}
         for step in range(self.start_step, self.start_step + n_steps):
-            batch = self.pipeline.host_batch(step)
+            batch = self.pipeline.place(self.pipeline.host_batch(step))
             t0 = time.perf_counter()
-            self.state, metrics = self.train_step(self.state, batch)
+            with use_mesh(self.mesh):
+                self.state, metrics = self.train_step(self.state, batch)
             loss = float(metrics["loss"])
             dt = time.perf_counter() - t0
             history["loss"].append(loss)

@@ -494,6 +494,39 @@ def test_commit_step_odd_itemsize_dtype_roundtrip(tmp_path, dtype):
         assert a.tobytes() == b.tobytes()
 
 
+def test_commit_step_chunked_bf16_roundtrip(tmp_path):
+    """A bf16 leaf above the chunk threshold (a published model's
+    embedding and its moments) commits chunked, full and then against its
+    parent's grid, and a fresh manager restores it bit for bit."""
+    import ml_dtypes
+    store = ArtifactStore(root=str(tmp_path), t_thr=float("inf"),
+                          chunk_threshold=64 * 1024, chunk_min=16 * 1024,
+                          chunk_avg=32 * 1024, chunk_max=64 * 1024)
+    cm = CheckpointManager(str(tmp_path), model_name="m", async_save=False,
+                           store=store)
+    rng = np.random.default_rng(0)
+    big = rng.standard_normal((256, 300)).astype(ml_dtypes.bfloat16)
+    states = [{"embed": big, "step": np.asarray(0, np.int32)},
+              {"embed": edit_bf16(big), "step": np.asarray(1, np.int32)}]
+    for i, s in enumerate(states):
+        cm.save(i, s, blocking=True)
+        e = cm.store.get_manifest(
+            cm.lineage.nodes[f"m/step{i}"].artifact_ref)["params"]["embed"]
+        assert e["kind"] == "chunked" and e["dtype"] == "bfloat16"
+    cold = CheckpointManager(str(tmp_path), model_name="m", async_save=False)
+    for i, s in enumerate(states):
+        restored, step = cold.restore(step=i, template=s)
+        assert step == i and restored["embed"].dtype == big.dtype
+        for a, b in zip(_leaves(s), _leaves(restored)):
+            assert a.tobytes() == b.tobytes()
+
+
+def edit_bf16(x):
+    out = x.copy()
+    out.reshape(-1)[1000:1100] += np.asarray(0.5, x.dtype)
+    return out
+
+
 def test_crash_before_manifest_lands_is_a_noop_recovery(tmp_path):
     cm = CheckpointManager(str(tmp_path), model_name="m", async_save=False)
     cm.save(1, _state(1), blocking=True)

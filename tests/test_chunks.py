@@ -19,11 +19,11 @@ CHUNK_KW = dict(chunk_threshold=64 * 1024, chunk_min=16 * 1024,
                 chunk_avg=32 * 1024, chunk_max=64 * 1024)
 
 
-def big_artifact(seed=0, rows=256, cols=300):
+def big_artifact(seed=0, rows=256, cols=300, dtype="float32"):
     rng = np.random.default_rng(seed)
-    w = rng.standard_normal((rows, cols)).astype(np.float32)
+    w = rng.standard_normal((rows, cols)).astype(dtype)
     g = LayerGraph.chain([LayerNode("big", "linear",
-                                    params={"w": ((rows, cols), "float32")})])
+                                    params={"w": ((rows, cols), dtype)})])
     return ModelArtifact(g, {"big/w": w}), w
 
 
@@ -80,6 +80,69 @@ def test_cut_points_boundary_stability_under_prefix_shift():
     assert len(common) >= 0.8 * max(1, len(tail_a))
 
 
+def _reference_cut_points(data, itemsize, min_size, avg_size, max_size,
+                          segments):
+    """The boundary rule stated plainly: per cut, hash a max_size
+    lookahead with the full 64-bit Gear hash, take the first candidate at
+    or past min_size, else cut at max_size. Repositories written with it
+    must keep their grids, so cut_points must give exactly these cuts."""
+    gear, w = chunklib._GEAR, chunklib.WINDOW
+    mask = np.uint64(avg_size - 1)
+
+    def snap(off):
+        return off // itemsize * itemsize
+
+    min_size = max(itemsize, snap(min_size) or itemsize)
+    max_size = max(min_size + itemsize, snap(max_size))
+    bounds = sorted({0, len(data), *[s for s in segments
+                                     if 0 < s < len(data)]})
+    cuts = []
+    for start, end in zip(bounds[:-1], bounds[1:]):
+        pos = 0
+        while end - start - pos > max_size:
+            win = np.frombuffer(data[start + pos:start + pos + max_size],
+                                np.uint8)
+            h = gear[0][win[w - 1:]]
+            for j in range(1, w):
+                h ^= gear[j][win[w - 1 - j:win.size - j]]
+            cut = max(itemsize, snap(max_size))
+            for c in np.flatnonzero((h & mask) == 0) + w - 1:
+                if snap(int(c) + 1) >= min_size:
+                    cut = snap(int(c) + 1)
+                    break
+            if end - start - (pos + cut) < itemsize:
+                break
+            pos += cut
+            cuts.append(start + pos)
+        cuts.append(end)
+    return sorted(set(c for c in cuts if 0 < c <= len(data)))
+
+
+@pytest.mark.parametrize("content,itemsize,sizes,segments", [
+    ("random", 1, (8 * 1024, 16 * 1024, 64 * 1024), ()),
+    ("floats", 4, (4, 4096, 8192), (100_003, 300_000)),
+    ("runs", 2, (1024, 65536, 4 * 1024), ()),
+    ("floats", 8, (256 * 1024, 1 << 20, 4 << 20), ()),
+])
+def test_cut_points_match_reference_rule(content, itemsize, sizes, segments):
+    rng = np.random.default_rng(7)
+    n = 3_000_000 if sizes[2] > 1 << 20 else 600_000
+    if content == "random":
+        data = rng.integers(0, 256, n, dtype=np.uint8).tobytes()
+    elif content == "floats":
+        data = rng.standard_normal(n // 4).astype(np.float32).tobytes()
+    else:
+        data = np.repeat(rng.integers(0, 256, n // 100, dtype=np.uint8),
+                         100).tobytes()
+    min_size, avg_size, max_size = sizes
+    got = chunklib.cut_points(_mem_read(data), len(data), itemsize,
+                              min_size=min_size, avg_size=avg_size,
+                              max_size=max_size, mode="cdc",
+                              segments=list(segments) or None)
+    assert got == _reference_cut_points(data, itemsize, min_size, avg_size,
+                                        max_size, segments)
+
+
 def test_segments_are_hard_cuts():
     data = bytes(range(256)) * 2048          # 512 KiB, highly regular
     seg = [200_000, 400_000]
@@ -105,9 +168,10 @@ def test_fixed_mode_grid():
 # chunked commit / checkout
 # ---------------------------------------------------------------------------
 
-def test_chunked_commit_checkout_bit_identity(tmp_path):
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_chunked_commit_checkout_bit_identity(tmp_path, dtype):
     store = ArtifactStore(root=str(tmp_path), **CHUNK_KW)
-    art, w = big_artifact()
+    art, w = big_artifact(dtype=dtype)
     ref = store.commit_artifact("m", art)
     e = store.get_manifest(ref)["params"]["big/w"]
     assert e["kind"] == "chunked" and len(e["chunks"]) > 1
@@ -116,7 +180,7 @@ def test_chunked_commit_checkout_bit_identity(tmp_path):
     np.testing.assert_array_equal(got, w)
     # the lazy-load path and the recursive path agree
     lazy = store.load_artifact(ref)
-    assert lazy.params.spec_of("big/w") == (w.shape, "float32")
+    assert lazy.params.spec_of("big/w") == (w.shape, dtype)
     np.testing.assert_array_equal(np.asarray(lazy.params["big/w"]), w)
 
 

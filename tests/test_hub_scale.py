@@ -79,6 +79,46 @@ def test_multi_tenant_routing_shared_cas_dedup(tmp_path, service_hub):
     check_service(service)
 
 
+_HUB_PROCESS = """
+import sys
+from repro.cli import main
+rc = main(["-C", sys.argv[1], "hub", "serve", "--port", "0"])
+import jax._src.xla_bridge as xla_bridge
+print("JAX_BACKENDS", sorted(xla_bridge._backends), flush=True)
+sys.exit(rc)
+"""
+
+
+def test_hub_process_starts_no_jax_backend(tmp_path):
+    """A hub only moves bytes. Starting a JAX backend would take the chip
+    from a ``serve`` or trainer on the same TPU host."""
+    import os
+    import signal
+    import subprocess
+    import sys
+    g = _seed(tmp_path / "a", seed=0)
+    base = g.store.load_artifact(g.nodes["m@v1"].artifact_ref)
+    g.add_node(finetune_like(base, seed=3), "m@v2")
+    g.add_version_edge("m@v1", "m@v2")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(sys.path))
+    proc = subprocess.Popen(
+        [sys.executable, "-c", _HUB_PROCESS, str(tmp_path / "hub")],
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+    try:
+        url = proc.stdout.readline().split(" at ")[1].split()[0]
+        t = HttpTransport(url)
+        assert push(g, t, state=RemoteState(g.path, "origin")).published
+        clone(url, str(tmp_path / "c"))
+        assert_bit_identical(g, _repo(tmp_path / "c"))
+        t.run_gc(confirm_cycles=1)
+        assert t.server_stats()["errors_500"] == 0
+    finally:
+        proc.send_signal(signal.SIGINT)
+        out, err = proc.communicate(timeout=60)
+    assert proc.returncode == 0, err[-2000:]
+    assert out.strip().splitlines()[-1] == "JAX_BACKENDS []"
+
+
 def test_token_hub_never_creates_repos_for_bad_tokens(tmp_path):
     service = HubService(str(tmp_path / "hub"), token="sekrit")
     server, _ = start_in_thread(service)

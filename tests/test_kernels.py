@@ -10,7 +10,7 @@ pytest.importorskip("hypothesis", reason="property tests need hypothesis "
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops
-from repro.kernels.ref import quant_scale
+from repro.kernels.ref import fingerprint_host, quant_scale
 
 SHAPES = [(8,), (100,), (128, 128), (257, 33), (1024,), (3, 5, 7),
           (2048, 128), (1, 1)]
@@ -37,9 +37,9 @@ def test_dequant_apply_kernel_matches_oracle(shape, dtype):
     q = jnp.asarray(rng.integers(-100, 100, size=shape), dtype=jnp.int32)
     out_ref = ops.dequant_apply(p1, q, backend="ref")
     out_pal = ops.dequant_apply(p1, q, backend="interpret")
-    np.testing.assert_allclose(np.asarray(out_ref, np.float32),
-                               np.asarray(out_pal, np.float32),
-                               rtol=1e-6, atol=1e-6)
+    # bit-identical: the kernel rounds q*scale before subtracting, as the
+    # oracle does, even where the compiler would fuse them into an FMA
+    np.testing.assert_array_equal(np.asarray(out_ref), np.asarray(out_pal))
 
 
 @pytest.mark.parametrize("shape", [(100,), (257, 33), (128, 128), (3, 5, 7)])
@@ -52,8 +52,7 @@ def test_chain_apply_kernel_matches_oracle(shape, k):
           for _ in range(k)]
     out_ref = ops.chain_apply(base, qs, backend="ref")
     out_pal = ops.chain_apply(base, qs, backend="interpret")
-    np.testing.assert_allclose(np.asarray(out_ref), np.asarray(out_pal),
-                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(out_ref), np.asarray(out_pal))
     # the fold identity vs single dequant of the exact int32 sum
     qsum = np.zeros(shape, np.int32)
     for q in qs:
@@ -71,7 +70,9 @@ def test_fingerprint_kernel_matches_oracle(shape, dtype):
         x = jnp.asarray(rng.integers(-1000, 1000, size=shape), dtype)
     else:
         x = jnp.asarray(rng.normal(size=shape), dtype)
-    assert ops.fingerprint(x, backend="ref") == ops.fingerprint(x, backend="interpret")
+    fp = ops.fingerprint(x, backend="ref")
+    assert fp == ops.fingerprint(x, backend="interpret")
+    assert fp == ops.fold_fingerprint(x, fingerprint_host(np.asarray(x)))
 
 
 def test_fingerprint_sensitivity():

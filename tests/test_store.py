@@ -28,6 +28,23 @@ def test_cas_dedup(tmp_path):
     np.testing.assert_array_equal(cas.get_tensor(k1), x)
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float8_e4m3fn"])
+def test_cas_tensor_keeps_ml_dtype(tmp_path, dtype):
+    """A bf16 train state committed full must read back as bf16, not as
+    raw void bytes — restore casts to the template's dtype, and fsck and
+    the pool re-derive the truth hash over (shape, dtype, bytes)."""
+    import ml_dtypes
+    cas = CAS(str(tmp_path), pack_threshold=0)
+    x = np.linspace(-2, 2, 3000, dtype=np.float32).reshape(30, 100)
+    x = x.astype(getattr(ml_dtypes, dtype))
+    k = cas.put_tensor(x)
+    cas.flush()
+    back = CAS(str(tmp_path)).get_tensor(k)
+    assert back.dtype == x.dtype and back.shape == x.shape
+    np.testing.assert_array_equal(back.view(np.uint8), x.view(np.uint8))
+    assert CAS(str(tmp_path)).fsck()["corrupt"] == []
+
+
 def test_cas_refcount_gc(tmp_path):
     cas = CAS(str(tmp_path))
     x = np.ones(100, np.float32)

@@ -60,3 +60,25 @@ def test_trainer_straggler_hook():
     ev = t.timer.record(9, 0.5)
     assert ev is not None
     assert t.policy.on_event(ev) in ("log", "rebalance", "evict")
+
+
+def test_trainer_on_a_mesh_resumes_through_restore_sharded(tmp_path):
+    """Given a mesh, the state is laid out by ``param_spec`` and a restart
+    resumes through ``restore_sharded`` onto that layout, bit for bit."""
+    import jax
+    from repro.dist.sharding import state_shardings
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
+                             ("data", "model"))
+    t1 = Trainer(CFG, batch=4, seq=16, checkpoint_dir=str(tmp_path),
+                 commit_every=2, seed=3, mesh=mesh)
+    t1.run(4)
+    saved = jax.device_get(t1.state)
+    t2 = Trainer(CFG, batch=4, seq=16, checkpoint_dir=str(tmp_path),
+                 commit_every=2, seed=3, mesh=mesh)
+    assert t2.start_step == 4
+    leaves = jax.tree_util.tree_leaves
+    for a, b, s in zip(leaves(saved), leaves(t2.state),
+                       leaves(state_shardings(mesh, t2.state))):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+        assert b.sharding.is_equivalent_to(s, b.ndim)
+    assert np.isfinite(t2.run(1)["loss"]).all()
