@@ -1,0 +1,8 @@
+"""Share of the traced training window in which no operation ran on the
+device."""
+
+from chipbench.metrics_common import idle_share
+
+
+def read(rec):
+    return idle_share(rec)
