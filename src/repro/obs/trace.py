@@ -16,6 +16,12 @@ null context manager — no ids, no clocks, no allocation beyond the call
 itself.  ``bench_obs`` measures (never asserts) that this keeps commit
 throughput within noise of an uninstrumented build.
 
+While tracing is on, every span also enters a ``jax.profiler``
+``TraceAnnotation`` of the same name on the same thread (its twin), so a
+profiler session records the program's spans on its own clock, beside the
+device operations. The twin carries the name only: annotation keyword
+arguments would rename the profiler event ``name#k=v#``.
+
 Export is the Chrome trace-event JSON Perfetto loads directly
 (``ph:"X"`` complete events, µs timestamps, per-thread ``thread_name``
 metadata).  ``span_id``/``parent_id`` ride in each event's ``args`` so
@@ -42,10 +48,12 @@ MAX_EVENTS = 200_000
 
 class _State:
     __slots__ = ("enabled", "lock", "events", "next_id", "t0_ns",
-                 "thread_names", "dropped")
+                 "thread_names", "dropped", "annotation")
 
     def __init__(self) -> None:
         self.enabled = False
+        #: ``jax.profiler.TraceAnnotation``, resolved by :func:`enable`
+        self.annotation = None
         self.lock = threading.Lock()
         self.events: List[Dict[str, Any]] = []
         self.next_id = 1
@@ -75,7 +83,8 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "cat", "args", "id", "parent_id", "t0", "_token")
+    __slots__ = ("name", "cat", "args", "id", "parent_id", "t0", "_token",
+                 "_twin")
 
     def __init__(self, name: str, cat: str, args: Dict[str, Any]) -> None:
         self.name = name
@@ -85,6 +94,7 @@ class _Span:
         self.parent_id: Optional[int] = None
         self.t0 = 0
         self._token: Optional[contextvars.Token] = None
+        self._twin = None
 
     def __enter__(self) -> "_Span":
         parent = _current.get()
@@ -93,11 +103,14 @@ class _Span:
             self.id = _state.next_id
             _state.next_id += 1
         self._token = _current.set(self)
+        self._twin = _state.annotation(self.name)
+        self._twin.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         dur_ns = time.perf_counter_ns() - self.t0
+        self._twin.__exit__(*exc)
         if self._token is not None:
             _current.reset(self._token)
         t = threading.current_thread()
@@ -145,6 +158,9 @@ def propagate(fn):
 
 
 def enable(on: bool = True) -> None:
+    if on and _state.annotation is None:
+        import jax.profiler    # here, not at import: only tracing needs it
+        _state.annotation = jax.profiler.TraceAnnotation
     _state.enabled = bool(on)
 
 
@@ -169,7 +185,7 @@ class tracing:
 
     def __enter__(self) -> None:
         self._prev = _state.enabled
-        _state.enabled = bool(self.on)
+        enable(self.on)
 
     def __exit__(self, *exc) -> bool:
         _state.enabled = self._prev

@@ -516,7 +516,10 @@ class ArtifactStore:
         def process(pair):
             pkey, ckey = pair
             p1 = np.asarray(pvals[pkey])
-            p2 = np.asarray(child.params[ckey])
+            value = child.params[ckey]
+            with span("commit.d2h", cat="store", key=ckey,
+                      n=getattr(value, "nbytes", 0)):
+                p2 = np.asarray(value)
             if p1.size == 0:
                 return None
             with span("commit.quantize", cat="store", key=ckey):
@@ -929,7 +932,8 @@ class ArtifactStore:
                     nb = int(np.asarray(value).nbytes)
                 if nb < self.chunk_threshold:
                     continue
-            out[key] = chunklib.as_source(value)
+            with span("commit.d2h", cat="store", key=key, n=int(nb)):
+                out[key] = chunklib.as_source(value)
         return out
 
     def _shard_segments(self, key: str, shape, itemsize: int):
@@ -1006,26 +1010,32 @@ class ArtifactStore:
             """Worker: returns (tag, meta, payload, truth_bytes)."""
             off, n = spans[idx]
             data = bytes(source.read(off, n))
-            ckey = "c_" + bytes_hash(data)
+            with span("chunk.hash", cat="store", n=n):
+                ckey = "c_" + bytes_hash(data)
             if delta_f32:
                 pitem = pe["chunks"][idx]
                 if pitem.get("c") == ckey:
                     return ("c", ckey, data, data)  # identical raw chunk
-                pbytes = self._chunk_value(parent_chain, idx)
+                with span("chunk.parent", cat="store", n=n):
+                    pbytes = self._chunk_value(parent_chain, idx)
                 if data == pbytes:
                     # identical truth, but the parent chunk has no raw
                     # object of its own — record a pass-through
                     return ("p", None, None, data)
                 child = np.frombuffer(data, dtype=np.float32)
                 parent = np.frombuffer(pbytes, dtype=np.float32)
-                q, nz, _narrow = host_snapshot(parent, child, self.eps)
-                # density is free from the snapshot kernel: ultra-sparse
-                # chunks (edit stragglers) switch to the sparse codec
-                ccod = pick_codec(int(nz), q.size, cod)
-                blob = ccod.encode(q)
+                with span("chunk.quantize", cat="store", n=n):
+                    q, nz, _narrow = host_snapshot(parent, child, self.eps)
+                with span("chunk.encode", cat="store", n=n):
+                    # density is free from the snapshot kernel: ultra-sparse
+                    # chunks (edit stragglers) switch to the sparse codec
+                    ccod = pick_codec(int(nz), q.size, cod)
+                    blob = ccod.encode(q)
                 if len(blob) < n:
-                    truth = host_dequant(parent, q, self.eps).tobytes()
-                    if truth == pbytes:
+                    with span("chunk.quantize", cat="store", n=n):
+                        truth = host_dequant(parent, q, self.eps).tobytes()
+                        same = truth == pbytes
+                    if same:
                         return ("p", None, None, truth)
                     return ("b", (str(q.dtype), ccod.name), blob, truth)
             return ("c", ckey, data, data)
@@ -1048,10 +1058,12 @@ class ArtifactStore:
                     results = [process(i) for i in idxs]
                 for idx, (tag, meta, payload, truth) in zip(idxs, results):
                     n = spans[idx][1]
-                    hasher.update(truth)
+                    with span("chunk.hash", cat="store", n=n):
+                        hasher.update(truth)
                     if tag == "c":
-                        had = self.cas.has(meta)
-                        self.cas.put_bytes(payload, key=meta)
+                        with span("chunk.write", cat="store", n=n):
+                            had = self.cas.has(meta)
+                            self.cas.put_bytes(payload, key=meta)
                         items[idx] = {"c": meta, "n": n}
                         with self._lock:
                             self.io_stats["chunks_written"] += 1
@@ -1060,7 +1072,8 @@ class ArtifactStore:
                             else:
                                 self.io_stats["chunk_bytes_written"] += n
                     elif tag == "b":
-                        bkey = self.cas.put_bytes(payload)
+                        with span("chunk.write", cat="store", n=n):
+                            bkey = self.cas.put_bytes(payload)
                         qdtype, codname = meta
                         items[idx] = {"b": bkey, "n": n, "q": qdtype}
                         if codname != self.codec:
@@ -1109,38 +1122,43 @@ class ArtifactStore:
 
         Walks down the chain until a raw ``c`` item, then applies the
         recorded per-chunk dequant hops back up (``p`` items copy through).
+        Every object is read before the first hop is decoded, so one
+        ``chunk.read`` and one ``chunk.decode`` span cover the chunk.
         Chunk reads bypass the mmap pool: checkout of a huge tensor must
         not charge mapped pages to the process RSS high-water mark."""
         level = 0
         hops: List[Tuple[Dict[str, Any], Dict[str, Any]]] = []
-        while True:
-            e = chain[level]
-            item = e["chunks"][idx]
-            if "c" in item:
-                base = self.cas.get_bytes_nomap(item["c"])
-                break
-            if "p" in item:
+        nbytes = int(chain[0]["chunks"][idx]["n"])
+        with span("chunk.read", cat="store", n=nbytes):
+            while True:
+                e = chain[level]
+                item = e["chunks"][idx]
+                if "c" in item:
+                    base = self.cas.get_bytes_nomap(item["c"])
+                    break
+                if "p" in item:
+                    level += 1
+                    continue
+                hops.append((e, item))
                 level += 1
-                continue
-            hops.append((e, item))
-            level += 1
+            hops.reverse()            # applied parent-first
+            blobs = [self.cas.get_bytes_nomap(item["b"]) for _, item in hops]
         with self._lock:
             self.io_stats["chunks_read"] += 1
         if not hops:
             return base
-        value = np.frombuffer(base, dtype=np.float32)
-        for e, item in reversed(hops):
-            blob = self.cas.get_bytes_nomap(item["b"])
-            n = int(item["n"]) // 4
-            # per-item ``k`` overrides the entry codec (density-adaptive
-            # sparse pick at commit time); absent means the entry default
-            q = get_codec(item.get("k", e["codec"])).decode(
-                blob, n, dtype=item.get("q", "int32"))
-            value = host_dequant(value, q, float(e["eps"]))
-            with self._lock:
-                self.io_stats["dequant_calls"] += 1
-                self.io_stats["chain_hops"] += 1
-        return value.tobytes()
+        with span("chunk.decode", cat="store", n=nbytes):
+            value = np.frombuffer(base, dtype=np.float32)
+            for (e, item), blob in zip(hops, blobs):
+                # per-item ``k`` overrides the entry codec (density-adaptive
+                # sparse pick at commit time); absent means the entry default
+                q = get_codec(item.get("k", e["codec"])).decode(
+                    blob, int(item["n"]) // 4, dtype=item.get("q", "int32"))
+                value = host_dequant(value, q, float(e["eps"]))
+                with self._lock:
+                    self.io_stats["dequant_calls"] += 1
+                    self.io_stats["chain_hops"] += 1
+            return value.tobytes()
 
     def _materialize_chunked(self, ref: str, key: str) -> np.ndarray:
         """Decode a chunked param into one preallocated destination array."""
@@ -1162,7 +1180,7 @@ class ArtifactStore:
         on_pool = threading.current_thread().name.startswith(
             "artifact-store-io")
         if not on_pool and self.io_workers > 1 and len(spans) > 2:
-            list(self._executor().map(fill, range(len(spans))))
+            list(self._executor().map(propagate(fill), range(len(spans))))
         else:
             for i in range(len(spans)):
                 fill(i)

@@ -91,6 +91,12 @@ def _keystr(path) -> str:
     return jax.tree_util.keystr(path, simple=True, separator="/")
 
 
+def _transfer(key: str, leaf, nbytes: int) -> np.ndarray:
+    """One leaf of a snapshot, copied device->host."""
+    with span("ckpt.transfer", cat="ckpt", key=key, n=nbytes):
+        return np.asarray(jax.device_get(leaf))
+
+
 def flatten_state(state) -> Dict[str, np.ndarray]:
     """Pytree -> flat {path: host ndarray}. Gathers from device (blocking)."""
     flat = {}
@@ -293,7 +299,7 @@ class CheckpointManager:
             nbytes = (int(np.prod(shape, dtype=np.int64))
                       * np.dtype(dt).itemsize) if dt is not None else 0
             if nbytes < self.fingerprint_min_bytes:
-                flat[key] = np.asarray(jax.device_get(leaf))
+                flat[key] = _transfer(key, leaf, nbytes)
                 continue
             if device_fp:
                 fp = self._device_fp(leaf)
@@ -302,9 +308,9 @@ class CheckpointManager:
                     flat[key] = None
                     skip.add(key)
                     continue
-                flat[key] = np.asarray(jax.device_get(leaf))
+                flat[key] = _transfer(key, leaf, nbytes)
             else:
-                arr = np.asarray(jax.device_get(leaf))
+                arr = _transfer(key, leaf, nbytes)
                 fp = self._host_fp(arr)
                 fps[key] = fp
                 if self._last_fps.get(key) == fp:
@@ -410,7 +416,7 @@ class CheckpointManager:
                     self._cond.notify_all()
 
     def wait(self) -> None:
-        with self._cond:
+        with span("ckpt.wait", cat="ckpt"), self._cond:
             while self._pending is not None or self._inflight:
                 self._cond.wait(timeout=0.05)
         self._check_error()

@@ -1,7 +1,9 @@
 """Observability layer (DESIGN.md §14): metrics registry, trace spans,
 Prometheus exposition on both daemons, retry/watcher visibility."""
 
+import contextlib
 import json
+import os
 import threading
 import urllib.error
 import urllib.request
@@ -10,7 +12,7 @@ import numpy as np
 import pytest
 
 from repro.cli import main as cli
-from repro.core import LineageGraph
+from repro.core import LineageGraph, ModelArtifact
 from repro.hub import HubApp
 from repro.hub import start_in_thread as hub_start
 from repro.obs import (REGISTRY, Histogram, Registry, propagate, reset_trace,
@@ -297,6 +299,165 @@ def test_chrome_trace_has_thread_metadata():
     assert any(m["name"] == "process_name" for m in metas)
     assert any(m["name"] == "thread_name" for m in metas)
     json.dumps(doc)  # exportable as-is
+
+
+def test_disabled_span_enters_no_annotation(monkeypatch):
+    from repro.obs import trace as obs_trace
+    entered = []
+
+    def annotation(name):
+        entered.append(name)
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(obs_trace._state, "annotation", annotation)
+    with span("invisible", cat="test", n=1):
+        pass
+    assert span("a") is span("b")  # the one cached null span
+    assert entered == [] and _span_events() == []
+    with tracing():
+        with span("seen", cat="test", n=1):
+            pass
+    assert entered == ["seen"]  # the name only, no arguments
+
+
+def test_spans_have_profiler_twins_on_one_clock(tmp_path):
+    """Every span is also a ``/host:`` event of the profiler's trace, on its
+    real thread; put on the profiler's clock through the window's anchor (as
+    the chip benchmark does), start and duration agree within 1 ms."""
+    import sys
+    import time
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from jax.profiler import ProfileData
+    from chipbench import trace as bench_trace
+
+    with bench_trace.Capture(str(tmp_path / "profile")) as cap:
+        with span("clock.outer", cat="test"):
+            time.sleep(0.003)
+            with span("clock.inner", cat="test"):
+                time.sleep(0.002)
+
+            def task():
+                with span("clock.pooled", cat="test"):
+                    time.sleep(0.002)
+
+            t = threading.Thread(target=propagate(task), name="clock-pool")
+            t.start()
+            t.join()
+    evs = bench_trace.events(cap.xplane())
+    (anchor,) = [e for e in evs if e["name"] == bench_trace.WINDOW_SPAN]
+    host = [e for e in evs if e["plane"].startswith("/host:")]
+    assert sorted(sp["name"] for sp in cap.spans) == [
+        "clock.inner", "clock.outer", "clock.pooled"]
+    for sp in cap.spans:
+        start = anchor["start_ns"] + sp["start_ns"]
+        (twin,) = [e for e in host if e["name"] == sp["name"]]
+        assert abs(twin["start_ns"] - start) < 1_000_000, sp
+        assert abs(twin["dur_ns"] - sp["dur_ns"]) < 1_000_000, sp
+    # one profiler line per thread (lines are named by the OS thread name,
+    # the same for both here, so they are told apart by position)
+    line_of = {}
+    for plane in ProfileData.from_file(cap.xplane()).planes:
+        for i, line in enumerate(plane.lines):
+            for ev in line.events:
+                line_of[ev.name] = (plane.name, i)
+    assert line_of["clock.inner"] == line_of["clock.outer"]
+    assert line_of["clock.pooled"] != line_of["clock.outer"]
+
+
+def _roots(evs):
+    by_id = {e["args"]["span_id"]: e for e in evs}
+    out = {}
+    for e in evs:
+        cur = e
+        while cur["args"]["parent_id"] is not None:
+            cur = by_id[cur["args"]["parent_id"]]
+        out[e["args"]["span_id"]] = cur["name"]
+    return out
+
+
+def _count(evs, name):
+    return sum(e["name"] == name for e in evs)
+
+
+def test_chunk_stage_spans_once_per_chunk_and_stage(tmp_path):
+    """A float32 chunked tensor two delta hops from its base: a commit and a
+    checkout open each ``chunk.*`` span once per chunk and stage (never per
+    hop), all under ``store.commit`` / ``store.checkout``; a direct
+    ``materialize_param`` fans its chunks out under its own span."""
+    from test_chunks import CHUNK_KW, big_artifact
+
+    store = ArtifactStore(root=str(tmp_path), io_workers=4, **CHUNK_KW)
+    art, w = big_artifact()
+    rng = np.random.default_rng(1)
+
+    def child(base):   # every chunk changes, every delta compresses
+        v = base + rng.normal(0, 1e-3, base.shape).astype(np.float32)
+        return ModelArtifact(art.graph, {"big/w": v}), v
+
+    r1 = store.commit_artifact("m1", art)
+    art2, w2 = child(w)
+    r2 = store.commit_artifact("m2", art2, parent_ref=r1)
+    art3, _ = child(w2)
+    store = ArtifactStore(root=str(tmp_path), io_workers=4, **CHUNK_KW)
+    with tracing():
+        r3 = store.commit_artifact("m3", art3, parent_ref=r2)
+    commit_evs = _span_events()
+    items = store.get_manifest(r3)["params"]["big/w"]["chunks"]
+    n = len(items)
+    assert n > 2 and all("b" in it for it in items)
+    assert all(e["args"]["n"] > 0 for e in commit_evs
+               if e["name"].startswith(("chunk.", "commit.d2h")))
+    assert {name: _count(commit_evs, name) for name in (
+        "commit.d2h", "chunk.hash", "chunk.parent", "chunk.read",
+        "chunk.decode", "chunk.quantize", "chunk.encode",
+        "chunk.write")} == {
+        "commit.d2h": 1, "chunk.hash": 2 * n, "chunk.parent": n,
+        "chunk.read": n, "chunk.decode": n, "chunk.quantize": 2 * n,
+        "chunk.encode": n, "chunk.write": n}
+    assert set(_roots(commit_evs).values()) == {"store.commit"}
+
+    reset_trace()
+    store = ArtifactStore(root=str(tmp_path), io_workers=4, **CHUNK_KW)
+    with tracing():
+        got = store.materialize_artifact(r3).params["big/w"]
+    evs = _span_events()
+    assert _count(evs, "chunk.read") == n and _count(evs, "chunk.decode") == n
+    assert set(_roots(evs).values()) == {"store.checkout"}
+
+    reset_trace()
+    store = ArtifactStore(root=str(tmp_path), io_workers=4, **CHUNK_KW)
+    with tracing():
+        direct = store.materialize_param(r3, "big/w")
+    evs = _span_events()
+    chunk_evs = [e for e in evs if e["name"].startswith("chunk.")]
+    assert len(chunk_evs) == 2 * n
+    # the fan-out ran on the pool, and propagate() kept the parent
+    assert threading.get_ident() not in {e["tid"] for e in chunk_evs}
+    assert set(_roots(evs).values()) == {"checkout.param"}
+    assert np.array_equal(got.view(np.uint32), direct.view(np.uint32))
+
+
+def test_checkpoint_save_spans_transfer_and_wait(tmp_path):
+    import jax.numpy as jnp
+    from repro.store.checkpoint import CheckpointManager
+
+    cm = CheckpointManager(str(tmp_path), model_name="m", async_save=True)
+    state = {"w": jnp.arange(64 * 1024, dtype=jnp.float32),
+             "b": jnp.ones((8,), jnp.float32)}
+    with tracing():
+        cm.save(1, state)
+        cm.wait()
+    cm.close()
+    evs = _span_events()
+    by_id = {e["args"]["span_id"]: e for e in evs}
+    transfers = [e for e in evs if e["name"] == "ckpt.transfer"]
+    assert sorted(e["args"]["key"] for e in transfers) == ["b", "w"]
+    assert {e["args"]["n"] for e in transfers} == {32, 256 * 1024}
+    assert all(by_id[e["args"]["parent_id"]]["name"] == "ckpt.snapshot"
+               for e in transfers)
+    assert _count(evs, "ckpt.wait") == 1
 
 
 # ---------------------------------------------------------------------------
