@@ -231,6 +231,43 @@ class FoldCache:
         return len(self._entries)
 
 
+class _ChunkPlan:
+    """One leaf in the commit's chunk stream: its grid, the parent's entry
+    and chunk chain when the grid is inherited, and what its consumed
+    chunks have produced so far (truth hash, chunk items)."""
+
+    def __init__(self, key: str, source, pe: Optional[Dict[str, Any]],
+                 parent_chain: Optional[List[Dict[str, Any]]],
+                 spans: List[Tuple[int, int]]) -> None:
+        self.key = key
+        self.source = source
+        self.pe = pe
+        self.parent_chain = parent_chain
+        self.spans = spans
+        self.dtype = np.dtype(source.dtype)
+        self.shape = tuple(int(d) for d in source.shape)
+        self.delta_f32 = (parent_chain is not None
+                          and self.dtype == np.float32)
+        self.hasher = TensorHasher(self.shape, self.dtype)
+        self.items: List[Optional[Dict[str, Any]]] = [None] * len(spans)
+
+    def entry(self, parent_ref: Optional[str], eps: float,
+              codec: str) -> Dict[str, Any]:
+        e: Dict[str, Any] = {"kind": "chunked",
+                             "hash": self.hasher.hexdigest(),
+                             "shape": list(self.shape),
+                             "dtype": str(self.dtype),
+                             "nbytes": int(self.source.nbytes),
+                             "chunks": self.items}
+        if self.pe is not None and any("b" in it or "p" in it
+                                       for it in self.items):
+            # at least one chunk is stored relative to the parent: record
+            # the chain link (and the decode parameters shared by all blobs)
+            e.update({"parent_ref": parent_ref, "parent_key": self.key,
+                      "eps": eps, "codec": codec})
+        return e
+
+
 class ArtifactStore:
     """The ``store`` object a :class:`repro.core.LineageGraph` plugs into."""
 
@@ -308,7 +345,8 @@ class ArtifactStore:
                   "chunk_bytes_written", "chunks_deduped",
                   "chunk_delta_blobs", "chunk_passthrough", "chunks_read",
                   "step_commits", "step_leaves_copied", "step_leaves_delta",
-                  "step_leaves_xdelta", "step_leaves_full"),
+                  "step_leaves_xdelta", "step_leaves_full",
+                  "chunk_head_waits", "chunk_window_stalls"),
             help="ArtifactStore I/O accounting")
         self._lock = threading.RLock()   # manifests dict + counters
         self._stats_path = (os.path.join(root, "store_stats.json")
@@ -426,9 +464,10 @@ class ArtifactStore:
                     artifact = result.reconstructed
 
         with self.cas.batch():  # one append handle per pack, one fsync
-            for key, source in chunk_sources.items():
-                entries[key] = self._commit_chunked(key, source, parent_ref,
-                                                    parent_manifest)
+            if chunk_sources:
+                entries.update(self._commit_chunked(
+                    list(chunk_sources.items()), parent_ref,
+                    parent_manifest))
             if depth == 0 and any(e.get("parent_ref")
                                   for e in entries.values()):
                 depth = parent_manifest["depth"] + 1
@@ -650,9 +689,9 @@ class ArtifactStore:
         (grid inheritance still dedups unchanged chunks; per-chunk
         quantized deltas only in the lossy tier), else a raw full tensor."""
         if self.chunk_threshold and value.nbytes >= self.chunk_threshold:
-            e = self._commit_chunked(key, chunklib.as_source(value),
+            e = self._commit_chunked([(key, chunklib.as_source(value))],
                                      parent_ref, parent_manifest,
-                                     lossless=lossless)
+                                     lossless=lossless)[key]
             if e.get("parent_ref"):
                 e["d"] = int(parent_manifest.get("depth", 0)) + 1
             return e
@@ -959,18 +998,24 @@ class ArtifactStore:
             return None
         return pe
 
-    def _commit_chunked(self, key: str, source, parent_ref: Optional[str],
+    def _commit_chunked(self, leaves: Sequence[Tuple[str, Any]],
+                        parent_ref: Optional[str],
                         parent_manifest: Optional[Dict[str, Any]],
-                        lossless: bool = False) -> Dict[str, Any]:
-        """Stream one large param into chunk objects; return its entry.
+                        lossless: bool = False) -> Dict[str, Dict[str, Any]]:
+        """Stream large params into chunk objects; return their entries.
 
-        The tensor is processed through a bounded window: chunks are read,
-        (optionally) delta-encoded against the parent's corresponding chunk
-        and written in batches sized so in-flight bytes stay within
+        Every chunk of every leaf in ``leaves`` (``(key, source)`` pairs)
+        goes through ONE bounded, in-order stream
+        (:func:`chunklib.ordered_stream`): workers read, (optionally)
+        delta-encode against the parent's corresponding chunk and key-hash
+        each chunk, admitted while the bytes in flight stay within
         ``chunk_window_bytes`` — the full tensor never exists in memory.
-        The entry's ``hash`` is the stored-truth tensor hash, accumulated
-        incrementally in chunk order (bit-identical to ``tensor_hash`` of
-        the materialized checkout).
+        This thread consumes the results strictly in chunk order: it
+        accumulates each leaf's stored-truth tensor hash (bit-identical to
+        ``tensor_hash`` of the materialized checkout), writes the objects
+        and assembles a leaf's entry when its last chunk is consumed. A
+        leaf's grid is planned when its first chunk comes up for admission,
+        so the next leaf's chunks run while this one's last are written.
 
         Grid inheritance: when the parent has a chunked entry of identical
         dtype/length, its grid is reused so chunks align 1:1 and each chunk
@@ -983,9 +1028,58 @@ class ArtifactStore:
         ``lossless`` (the exact checkpoint tier, DESIGN.md §15) disables
         the quantized per-chunk delta path: the inherited grid still
         dedups unchanged chunks by content key, but changed chunks store
-        raw bytes so the entry's truth IS the live value bit-for-bit."""
+        raw bytes so the entry's truth IS the live value bit-for-bit.
+
+        A failure anywhere in the stream drops the references this call
+        took on CAS objects before it propagates: no entry is returned."""
+        entries: Dict[str, Dict[str, Any]] = {}
+        taken: List[str] = []
+        n_chunks = 0
+
+        def tasks():
+            nonlocal n_chunks
+            for key, source in leaves:
+                plan = self._chunk_plan(key, source, parent_ref,
+                                        parent_manifest, lossless)
+                n_chunks += len(plan.spans)
+                for idx in range(len(plan.spans)):
+                    yield plan, idx
+
+        def consume(task, result):
+            plan, idx = task
+            self._chunk_consume(plan, idx, result, taken)
+            if idx == len(plan.spans) - 1:
+                entries[plan.key] = plan.entry(parent_ref, self.eps,
+                                               self.codec)
+
+        pool = self._executor() if self.io_workers > 1 else None
+        with span("commit.chunk_stream", cat="store",
+                  leaves=len(leaves)) as sp:
+            try:
+                # a chunk in flight holds ~4x its bytes (child, parent, q,
+                # blob): the window bounds peak memory, not a batch count
+                waits, stalls = chunklib.ordered_stream(
+                    tasks(), propagate(lambda t: self._chunk_work(*t)),
+                    consume, cost=lambda t: 4 * t[0].spans[t[1]][1],
+                    window=self.chunk_window_bytes, executor=pool)
+            except BaseException:
+                with self.cas.batched_refcounts():
+                    for k in taken:
+                        self.cas.decref(k)
+                raise
+            with self._lock:
+                self.io_stats["chunk_head_waits"] += waits
+                self.io_stats["chunk_window_stalls"] += stalls
+            if sp is not None:
+                sp.args.update(chunks=n_chunks, chunk_head_waits=waits,
+                               chunk_window_stalls=stalls)
+        return entries
+
+    def _chunk_plan(self, key: str, source, parent_ref: Optional[str],
+                    parent_manifest: Optional[Dict[str, Any]],
+                    lossless: bool) -> "_ChunkPlan":
+        """One leaf's grid, parent chunk chain and truth hasher."""
         dtype = np.dtype(source.dtype)
-        shape = tuple(int(d) for d in source.shape)
         nbytes = int(source.nbytes)
         pe = self._chunk_parent_entry(key, parent_ref, parent_manifest,
                                       source)
@@ -999,103 +1093,81 @@ class ArtifactStore:
                 source.read, nbytes, dtype.itemsize,
                 min_size=self.chunk_min, avg_size=self.chunk_avg,
                 max_size=self.chunk_max, mode=self.chunk_mode,
-                segments=self._shard_segments(key, shape, dtype.itemsize))
-        spans = chunklib.spans_of(cuts)
-        delta_f32 = parent_chain is not None and dtype == np.float32
-        cod = self._codec_obj
-        hasher = TensorHasher(shape, dtype)
-        items: List[Optional[Dict[str, Any]]] = [None] * len(spans)
+                segments=self._shard_segments(
+                    key, tuple(int(d) for d in source.shape),
+                    dtype.itemsize))
+        return _ChunkPlan(key, source, pe, parent_chain,
+                          chunklib.spans_of(cuts))
 
-        def process(idx: int):
-            """Worker: returns (tag, meta, payload, truth_bytes)."""
-            off, n = spans[idx]
-            data = bytes(source.read(off, n))
-            with span("chunk.hash", cat="store", n=n):
-                ckey = "c_" + bytes_hash(data)
-            if delta_f32:
-                pitem = pe["chunks"][idx]
-                if pitem.get("c") == ckey:
-                    return ("c", ckey, data, data)  # identical raw chunk
-                with span("chunk.parent", cat="store", n=n):
-                    pbytes = self._chunk_value(parent_chain, idx)
-                if data == pbytes:
-                    # identical truth, but the parent chunk has no raw
-                    # object of its own — record a pass-through
-                    return ("p", None, None, data)
-                child = np.frombuffer(data, dtype=np.float32)
-                parent = np.frombuffer(pbytes, dtype=np.float32)
+    def _chunk_work(self, plan: "_ChunkPlan", idx: int):
+        """Worker: one chunk's (tag, meta, payload, truth_bytes)."""
+        off, n = plan.spans[idx]
+        data = bytes(plan.source.read(off, n))
+        with span("chunk.hash", cat="store", n=n):
+            ckey = "c_" + bytes_hash(data)
+        if plan.delta_f32:
+            pitem = plan.pe["chunks"][idx]
+            if pitem.get("c") == ckey:
+                return ("c", ckey, data, data)  # identical raw chunk
+            with span("chunk.parent", cat="store", n=n):
+                pbytes = self._chunk_value(plan.parent_chain, idx)
+            if data == pbytes:
+                # identical truth, but the parent chunk has no raw object of
+                # its own — record a pass-through
+                return ("p", None, None, data)
+            child = np.frombuffer(data, dtype=np.float32)
+            parent = np.frombuffer(pbytes, dtype=np.float32)
+            with span("chunk.quantize", cat="store", n=n):
+                q, nz, _narrow = host_snapshot(parent, child, self.eps)
+            with span("chunk.encode", cat="store", n=n):
+                # density is free from the snapshot kernel: ultra-sparse
+                # chunks (edit stragglers) switch to the sparse codec
+                ccod = pick_codec(int(nz), q.size, self._codec_obj)
+                blob = ccod.encode(q)
+            if len(blob) < n:
                 with span("chunk.quantize", cat="store", n=n):
-                    q, nz, _narrow = host_snapshot(parent, child, self.eps)
-                with span("chunk.encode", cat="store", n=n):
-                    # density is free from the snapshot kernel: ultra-sparse
-                    # chunks (edit stragglers) switch to the sparse codec
-                    ccod = pick_codec(int(nz), q.size, cod)
-                    blob = ccod.encode(q)
-                if len(blob) < n:
-                    with span("chunk.quantize", cat="store", n=n):
-                        truth = host_dequant(parent, q, self.eps).tobytes()
-                        same = truth == pbytes
-                    if same:
-                        return ("p", None, None, truth)
-                    return ("b", (str(q.dtype), ccod.name), blob, truth)
-            return ("c", ckey, data, data)
+                    truth = host_dequant(parent, q, self.eps).tobytes()
+                    same = truth == pbytes
+                if same:
+                    return ("p", None, None, truth)
+                return ("b", (str(q.dtype), ccod.name), blob, truth)
+        return ("c", ckey, data, data)
 
-        # Bounded fan-out: each in-flight chunk holds ~4x its bytes (child,
-        # parent, q, blob), so batches of window/(4*max_chunk) keep peak
-        # in-flight memory within the configured window.
-        max_len = max(n for _, n in spans)
-        batch = max(1, self.chunk_window_bytes // max(1, 4 * max_len))
-        use_pool = (self.io_workers > 1 and batch > 1 and len(spans) > 1)
-        stream_span = span("commit.chunk_stream", cat="store", key=key,
-                           chunks=len(spans), batch=batch)
-        with stream_span:
-            for lo in range(0, len(spans), batch):
-                idxs = list(range(lo, min(len(spans), lo + batch)))
-                if use_pool and len(idxs) > 1:
-                    results = list(self._executor().map(propagate(process),
-                                                        idxs))
+    def _chunk_consume(self, plan: "_ChunkPlan", idx: int, result,
+                       taken: List[str]) -> None:
+        """In chunk order: hash the truth, write the object, record the
+        item and count it. ``taken`` collects the CAS keys referenced."""
+        tag, meta, payload, truth = result
+        n = plan.spans[idx][1]
+        with span("chunk.hash", cat="store", n=n):
+            plan.hasher.update(truth)
+        if tag == "c":
+            with span("chunk.write", cat="store", n=n):
+                had = self.cas.has(meta)
+                self.cas.put_bytes(payload, key=meta)
+            taken.append(meta)
+            plan.items[idx] = {"c": meta, "n": n}
+            with self._lock:
+                self.io_stats["chunks_written"] += 1
+                if had:
+                    self.io_stats["chunks_deduped"] += 1
                 else:
-                    results = [process(i) for i in idxs]
-                for idx, (tag, meta, payload, truth) in zip(idxs, results):
-                    n = spans[idx][1]
-                    with span("chunk.hash", cat="store", n=n):
-                        hasher.update(truth)
-                    if tag == "c":
-                        with span("chunk.write", cat="store", n=n):
-                            had = self.cas.has(meta)
-                            self.cas.put_bytes(payload, key=meta)
-                        items[idx] = {"c": meta, "n": n}
-                        with self._lock:
-                            self.io_stats["chunks_written"] += 1
-                            if had:
-                                self.io_stats["chunks_deduped"] += 1
-                            else:
-                                self.io_stats["chunk_bytes_written"] += n
-                    elif tag == "b":
-                        with span("chunk.write", cat="store", n=n):
-                            bkey = self.cas.put_bytes(payload)
-                        qdtype, codname = meta
-                        items[idx] = {"b": bkey, "n": n, "q": qdtype}
-                        if codname != self.codec:
-                            items[idx]["k"] = codname
-                        with self._lock:
-                            self.io_stats["chunk_delta_blobs"] += 1
-                            self.io_stats["chunk_bytes_written"] += len(payload)
-                    else:
-                        items[idx] = {"p": 1, "n": n}
-                        with self._lock:
-                            self.io_stats["chunk_passthrough"] += 1
-
-        entry: Dict[str, Any] = {"kind": "chunked",
-                                 "hash": hasher.hexdigest(),
-                                 "shape": list(shape), "dtype": str(dtype),
-                                 "nbytes": nbytes, "chunks": items}
-        if pe is not None and any("b" in it or "p" in it for it in items):
-            # at least one chunk is stored relative to the parent: record
-            # the chain link (and the decode parameters shared by all blobs)
-            entry.update({"parent_ref": parent_ref, "parent_key": key,
-                          "eps": self.eps, "codec": self.codec})
-        return entry
+                    self.io_stats["chunk_bytes_written"] += n
+        elif tag == "b":
+            with span("chunk.write", cat="store", n=n):
+                bkey = self.cas.put_bytes(payload)
+            taken.append(bkey)
+            qdtype, codname = meta
+            plan.items[idx] = {"b": bkey, "n": n, "q": qdtype}
+            if codname != self.codec:
+                plan.items[idx]["k"] = codname
+            with self._lock:
+                self.io_stats["chunk_delta_blobs"] += 1
+                self.io_stats["chunk_bytes_written"] += len(payload)
+        else:
+            plan.items[idx] = {"p": 1, "n": n}
+            with self._lock:
+                self.io_stats["chunk_passthrough"] += 1
 
     def _chunk_chain(self, ref: str, key: str) -> List[Dict[str, Any]]:
         """Chunked entries child-first along parent links (cycle-checked)."""

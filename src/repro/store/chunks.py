@@ -30,8 +30,11 @@ searches the sorted candidate positions.
 
 from __future__ import annotations
 
+import collections
 import os
-from typing import Callable, List, Optional, Sequence, Tuple
+import threading
+from concurrent import futures
+from typing import Any, Callable, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -169,6 +172,89 @@ def spans_of(cuts: Sequence[int]) -> List[Tuple[int, int]]:
         out.append((prev, c - prev))
         prev = c
     return out
+
+
+_END = object()   # ordered_stream: no task drawn yet / tasks exhausted
+
+
+def ordered_stream(tasks: Iterable, work: Callable, consume: Callable,
+                   cost: Callable[[Any], int], window: int,
+                   executor=None) -> Tuple[int, int]:
+    """Run ``work(task)`` for every task on ``executor`` and hand each
+    result to ``consume(task, result)`` strictly in task order.
+
+    Admission is by cost, not by batch: a task is submitted as soon as its
+    ``cost`` plus that of every task admitted but not yet consumed stays
+    within ``window``; a task costlier than the window runs alone, once
+    nothing else is in flight. After each consumed result more tasks are
+    admitted, so the workers keep running while the caller consumes. Tasks
+    are drawn from ``tasks`` lazily, on the calling thread, one admission
+    ahead of their submission.
+
+    On any failure (a task, ``consume`` or the task iterator raising)
+    admission stops, tasks not yet started are cancelled, running ones are
+    waited for, and the first error in task order is re-raised here; the
+    executor stays usable. Without an executor the tasks run inline.
+
+    Returns ``(head_waits, window_stalls)``: how often the next result in
+    order was still unfinished when the caller wanted it, and how often
+    admission stopped because the next task would exceed the window."""
+    if executor is None:
+        for task in tasks:
+            consume(task, work(task))
+        return 0, 0
+    it = iter(tasks)
+    pending: "collections.deque" = collections.deque()  # (task, future, cost)
+    failed = threading.Event()
+    held = 0
+    nxt: Any = _END
+    head_waits = stalls = 0
+
+    def note(fut) -> None:
+        if not fut.cancelled() and fut.exception() is not None:
+            failed.set()
+
+    def admit() -> None:
+        nonlocal held, nxt, stalls
+        while not failed.is_set():
+            if nxt is _END:
+                nxt = next(it, _END)
+                if nxt is _END:
+                    return
+            c = int(cost(nxt))
+            if pending and held + c > window:
+                stalls += 1
+                return
+            fut = executor.submit(work, nxt)
+            fut.add_done_callback(note)
+            pending.append((nxt, fut, c))
+            held += c
+            nxt = _END
+
+    try:
+        admit()
+        while pending:
+            task, fut, c = pending[0]
+            if not fut.done():
+                head_waits += 1
+            result = fut.result()
+            if failed.is_set():
+                break
+            consume(task, result)
+            pending.popleft()
+            held -= c
+            admit()
+        if failed.is_set():  # a later task failed: raise the first, in order
+            for _, fut, _ in pending:   # the pool runs them FIFO: only
+                fut.cancel()            # tasks after the failure are unstarted
+            for _, fut, _ in pending:
+                fut.result()
+    except BaseException:
+        for _, fut, _ in pending:
+            fut.cancel()
+        futures.wait([fut for _, fut, _ in pending])
+        raise
+    return head_waits, stalls
 
 
 # ---------------------------------------------------------------------------

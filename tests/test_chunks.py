@@ -3,14 +3,17 @@ chunk-granular dedup/fsck/sync, shard-scoped fetch, ranged transfer."""
 
 import json
 import os
+import sys
+import threading
 
 import numpy as np
 import pytest
 
 from repro.core import LayerGraph, LayerNode, LineageGraph, ModelArtifact
+from repro.obs import export_chrome_trace, reset_trace, tracing
 from repro.store import ArtifactStore, CAS
 from repro.store import chunks as chunklib
-from repro.common.hashing import tensor_hash
+from repro.common.hashing import bytes_hash, tensor_hash
 from repro.remote.sync import fetch_objects, fetch_param_shard
 from repro.remote.transport import LocalTransport
 
@@ -247,6 +250,209 @@ def test_sub_threshold_params_unchanged(tmp_path):
     art2, _ = big_artifact()
     ref2 = off.commit_artifact("m", art2)
     assert off.get_manifest(ref2)["params"]["big/w"]["kind"] == "full"
+
+
+# ---------------------------------------------------------------------------
+# the commit's chunk stream: one bounded, in-order stream over every leaf
+# ---------------------------------------------------------------------------
+
+# a/w stays frozen along the chain, d/w changes below the quantization step
+# (pass-through chunks), f/w is under the chunk threshold
+STREAM_LEAVES = {"a/w": (200, 200), "b/w": (256, 300), "c/w": (96, 256),
+                 "d/w": (130, 333), "e/w": (400, 200), "f/w": (16, 16)}
+CHUNKED = [k for k in STREAM_LEAVES if k != "f/w"]
+
+
+def _stream_graph():
+    return LayerGraph.chain([
+        LayerNode(k.split("/")[0], "linear", params={"w": (s, "float32")})
+        for k, s in STREAM_LEAVES.items()])
+
+
+def _stream_finetune(params, seed):
+    rng = np.random.default_rng(seed)
+    out = {}
+    for k, v in params.items():
+        if k == "a/w":
+            out[k] = v
+            continue
+        scale = 1e-6 if k == "d/w" else 1e-2
+        mask = rng.random(v.shape) < 0.1
+        noise = rng.standard_normal(v.shape) * scale
+        out[k] = (v + np.where(mask, noise, 0.0)).astype(np.float32)
+    return out
+
+
+def _stream_chain(root, **kw):
+    """A depth-2 chunked chain; returns the store, its refs, the tip's
+    params and a child of the tip not yet committed."""
+    store = ArtifactStore(root=str(root), **dict(CHUNK_KW, **kw))
+    rng = np.random.default_rng(0)
+    params = {k: rng.standard_normal(s).astype(np.float32)
+              for k, s in STREAM_LEAVES.items()}
+    refs = [store.commit_artifact("c0", ModelArtifact(_stream_graph(),
+                                                      params))]
+    for k in (1, 2):
+        params = _stream_finetune(params, k)
+        refs.append(store.commit_artifact(
+            f"c{k}", ModelArtifact(_stream_graph(), params),
+            parent_ref=refs[-1]))
+    return store, refs, _stream_finetune(params, 3)
+
+
+STREAM_COUNTERS = ("chunks_written", "chunks_deduped", "chunk_delta_blobs",
+                   "chunk_passthrough")
+
+
+@pytest.mark.parametrize("window", ["below_one_chunk", "default"])
+@pytest.mark.parametrize("io_workers", [1, 4, 16])
+def test_chunk_stream_commit_is_byte_identical(tmp_path, io_workers, window):
+    """The stream changes when chunks run, never what a commit stores: the
+    same manifest ref, CAS objects, refcounts and chunk counters as a serial
+    commit, whatever the workers and the window (16 workers, more than the
+    cores, with threads switching every 10 us)."""
+    def child_commit(root, **kw):
+        store, refs, child = _stream_chain(root, **kw)
+        store.reset_io_stats()
+        ref = store.commit_artifact("c3", ModelArtifact(_stream_graph(),
+                                                        child),
+                                    parent_ref=refs[-1])
+        return store, ref
+
+    want_store, want = child_commit(tmp_path / "serial", io_workers=1)
+    kw = ({"chunk_window_bytes": CHUNK_KW["chunk_min"]}
+          if window == "below_one_chunk" else {})
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        store, got = child_commit(tmp_path / "stream",
+                                  io_workers=io_workers, **kw)
+    finally:
+        sys.setswitchinterval(interval)
+    assert got == want
+    assert set(store.cas.keys()) == set(want_store.cas.keys())
+    assert store.cas.refcounts == want_store.cas.refcounts
+    counts = {k: store.io_stats[k] for k in STREAM_COUNTERS}
+    assert counts == {k: want_store.io_stats[k] for k in STREAM_COUNTERS}
+    assert all(counts.values()), counts   # every kind of chunk occurs
+    entries = store.get_manifest(got)["params"]
+    assert [k for k in entries if entries[k]["kind"] == "chunked"] \
+        == CHUNKED
+    for k in CHUNKED:
+        assert entries[k]["hash"] == tensor_hash(
+            store.materialize_param(got, k))
+
+
+class _Ledger:
+    """Chunk bytes read from the sources and not yet consumed, the consume
+    marker being the chunk's ``put_bytes`` under its content key."""
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.open = {}
+        self.peak_bytes = self.peak_chunks = 0
+
+    def read(self, data):
+        with self.lock:
+            self.open["c_" + bytes_hash(data)] = len(data)
+            self.peak_bytes = max(self.peak_bytes, sum(self.open.values()))
+            self.peak_chunks = max(self.peak_chunks, len(self.open))
+
+    def consumed(self, key):
+        with self.lock:
+            self.open.pop(key, None)
+
+
+class _TrackedSource(chunklib.ArraySource):
+    def __init__(self, arr, ledger):
+        super().__init__(arr)
+        self._ledger = ledger
+
+    def read(self, offset, size):
+        data = super().read(offset, size)
+        self._ledger.read(bytes(data))
+        return data
+
+
+@pytest.mark.parametrize("window", [CHUNK_KW["chunk_min"],
+                                    12 * CHUNK_KW["chunk_max"]])
+def test_chunk_stream_stays_within_window(tmp_path, monkeypatch, window):
+    """Chunks in flight never hold more than the window (4 x their bytes
+    each); one costlier than the window runs alone. The counters land in
+    ``io_stats`` and on the commit's one ``commit.chunk_stream`` span."""
+    store = ArtifactStore(root=str(tmp_path), io_workers=4,
+                          chunk_window_bytes=window, chunk_mode="fixed",
+                          **CHUNK_KW)
+    ledger = _Ledger()
+    put_bytes = store.cas.put_bytes
+
+    def marked_put(data, key=None, **kw):
+        if key is not None:
+            ledger.consumed(key)
+        return put_bytes(data, key=key, **kw)
+
+    monkeypatch.setattr(store.cas, "put_bytes", marked_put)
+    rng = np.random.default_rng(0)
+    params = {k: rng.standard_normal(s).astype(np.float32)
+              for k, s in STREAM_LEAVES.items()}
+    params.update({k: _TrackedSource(params[k], ledger) for k in CHUNKED})
+    reset_trace()
+    try:
+        with tracing():
+            ref = store.commit_artifact(
+                "m", ModelArtifact(_stream_graph(), params))
+        streams = [e for e in export_chrome_trace()["traceEvents"]
+                   if e.get("name") == "commit.chunk_stream"]
+    finally:
+        reset_trace()
+    entries = store.get_manifest(ref)["params"]
+    n_chunks = sum(len(entries[k]["chunks"]) for k in CHUNKED)
+    assert ledger.open == {} and ledger.peak_chunks > 0
+    assert 4 * ledger.peak_bytes <= max(window, 4 * CHUNK_KW["chunk_max"])
+    if window < 4 * CHUNK_KW["chunk_min"]:
+        assert ledger.peak_chunks == 1      # every chunk admitted alone
+    else:
+        assert 4 * ledger.peak_bytes <= window
+        assert ledger.peak_chunks > 1
+    stalls = store.io_stats["chunk_window_stalls"]
+    assert stalls > 0
+    assert len(streams) == 1
+    args = streams[0]["args"]
+    assert (args["leaves"], args["chunks"]) == (len(CHUNKED), n_chunks)
+    assert args["chunk_window_stalls"] == stalls
+    assert args["chunk_head_waits"] == store.io_stats["chunk_head_waits"]
+
+
+class _FailingSource(chunklib.ArraySource):
+    """Raises on any read past its first chunk."""
+
+    def read(self, offset, size):
+        if offset > 0:
+            raise OSError("chunk source read failed")
+        return super().read(offset, size)
+
+
+@pytest.mark.parametrize("io_workers", [1, 4])
+def test_chunk_stream_failure_publishes_nothing(tmp_path, io_workers):
+    """A read failing in the second leaf fails the commit: no manifest, the
+    references its first (frozen, deduped) leaf took are dropped so fsck
+    stays clean, and the same store's pool commits next."""
+    store, refs, child = _stream_chain(tmp_path, io_workers=io_workers)
+    manifests = {k for k in store.cas.keys() if k.startswith("m_")}
+    bad = dict(child, **{"b/w": _FailingSource(child["b/w"])})
+    with pytest.raises(OSError, match="chunk source read failed"):
+        store.commit_artifact("bad", ModelArtifact(_stream_graph(), bad),
+                              parent_ref=refs[-1])
+    assert {k for k in store.cas.keys() if k.startswith("m_")} == manifests
+    report = store.fsck(refs)
+    assert report["ok"], report
+    ref = store.commit_artifact("c3", ModelArtifact(_stream_graph(), child),
+                                parent_ref=refs[-1])
+    for k in CHUNKED:
+        assert store.get_manifest(ref)["params"][k]["hash"] == tensor_hash(
+            store.materialize_param(ref, k))
+    report = store.fsck(refs + [ref])
+    assert report["ok"], report
 
 
 # ---------------------------------------------------------------------------
