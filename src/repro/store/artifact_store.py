@@ -50,6 +50,7 @@ import json
 import os
 import threading
 from collections import OrderedDict
+from concurrent import futures
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -62,7 +63,8 @@ from repro.obs import REGISTRY, propagate, span
 from repro.store import chunks as chunklib
 from repro.store.cas import CAS, DEFAULT_PACK_THRESHOLD
 from repro.store.codecs import (bitpattern_apply, bitpattern_delta,
-                                get_codec, pick_codec)
+                                bitpattern_delta_sparse, get_codec,
+                                pick_codec)
 from repro.store.delta import (CompressResult, ParamDelta, decode_q,
                                decompress_param, delta_compression,
                                host_dequant, host_snapshot,
@@ -231,10 +233,19 @@ class FoldCache:
         return len(self._entries)
 
 
+#: Codec of the lossless chunk hops: the sparse layout at zlib level 1.
+_XDELTA_CODEC = get_codec("sparse", preset=1)
+
+
 class _ChunkPlan:
     """One leaf in the commit's chunk stream: its grid, the parent's entry
     and chunk chain when the grid is inherited, and what its consumed
-    chunks have produced so far (truth hash, chunk items)."""
+    chunks have produced so far (truth hash, chunk items).
+
+    ``hop`` says how a changed chunk is stored against the parent's truth:
+    ``"q"`` a quantized delta (float32), ``"x"`` a lossless bit-pattern
+    delta (any other dtype), ``None`` raw (no parent chain, or the exact
+    tier)."""
 
     def __init__(self, key: str, source, pe: Optional[Dict[str, Any]],
                  parent_chain: Optional[List[Dict[str, Any]]],
@@ -246,8 +257,8 @@ class _ChunkPlan:
         self.spans = spans
         self.dtype = np.dtype(source.dtype)
         self.shape = tuple(int(d) for d in source.shape)
-        self.delta_f32 = (parent_chain is not None
-                          and self.dtype == np.float32)
+        self.hop = (None if parent_chain is None
+                    else "q" if self.dtype == np.float32 else "x")
         self.hasher = TensorHasher(self.shape, self.dtype)
         self.items: List[Optional[Dict[str, Any]]] = [None] * len(spans)
 
@@ -346,7 +357,8 @@ class ArtifactStore:
                   "chunk_delta_blobs", "chunk_passthrough", "chunks_read",
                   "step_commits", "step_leaves_copied", "step_leaves_delta",
                   "step_leaves_xdelta", "step_leaves_full",
-                  "chunk_head_waits", "chunk_window_stalls"),
+                  "chunk_head_waits", "chunk_window_stalls",
+                  "chunk_xdelta_blobs", "chunk_xdelta_bytes"),
             help="ArtifactStore I/O accounting")
         self._lock = threading.RLock()   # manifests dict + counters
         self._stats_path = (os.path.join(root, "store_stats.json")
@@ -1020,21 +1032,22 @@ class ArtifactStore:
         Grid inheritance: when the parent has a chunked entry of identical
         dtype/length, its grid is reused so chunks align 1:1 and each chunk
         stores as (a) a reference to the parent's identical raw chunk, (b) a
-        quantized per-chunk delta blob, (c) a pass-through marker (``p``:
+        per-chunk delta blob: quantized for float32, a lossless bit-pattern
+        hop (``x``) for any other dtype, (c) a pass-through marker (``p``:
         bit-identical to the parent chunk's truth), or (d) a fresh raw
         ``c_`` object. Without an inheritable grid, content-defined (or
         fixed) boundaries are computed and every chunk stores raw.
 
         ``lossless`` (the exact checkpoint tier, DESIGN.md §15) disables
-        the quantized per-chunk delta path: the inherited grid still
-        dedups unchanged chunks by content key, but changed chunks store
-        raw bytes so the entry's truth IS the live value bit-for-bit.
+        the per-chunk delta paths: the inherited grid still dedups
+        unchanged chunks by content key, but changed chunks store raw
+        bytes so the entry's truth IS the live value bit-for-bit.
 
         A failure anywhere in the stream drops the references this call
         took on CAS objects before it propagates: no entry is returned."""
         entries: Dict[str, Dict[str, Any]] = {}
         taken: List[str] = []
-        n_chunks = 0
+        n_chunks = n_xdelta = 0
 
         def tasks():
             nonlocal n_chunks
@@ -1046,8 +1059,10 @@ class ArtifactStore:
                     yield plan, idx
 
         def consume(task, result):
+            nonlocal n_xdelta
             plan, idx = task
             self._chunk_consume(plan, idx, result, taken)
+            n_xdelta += result[0] == "x"
             if idx == len(plan.spans) - 1:
                 entries[plan.key] = plan.entry(parent_ref, self.eps,
                                                self.codec)
@@ -1072,7 +1087,8 @@ class ArtifactStore:
                 self.io_stats["chunk_window_stalls"] += stalls
             if sp is not None:
                 sp.args.update(chunks=n_chunks, chunk_head_waits=waits,
-                               chunk_window_stalls=stalls)
+                               chunk_window_stalls=stalls,
+                               xdelta_chunks=n_xdelta)
         return entries
 
     def _chunk_plan(self, key: str, source, parent_ref: Optional[str],
@@ -1105,7 +1121,7 @@ class ArtifactStore:
         data = bytes(plan.source.read(off, n))
         with span("chunk.hash", cat="store", n=n):
             ckey = "c_" + bytes_hash(data)
-        if plan.delta_f32:
+        if plan.hop is not None:
             pitem = plan.pe["chunks"][idx]
             if pitem.get("c") == ckey:
                 return ("c", ckey, data, data)  # identical raw chunk
@@ -1115,6 +1131,15 @@ class ArtifactStore:
                 # identical truth, but the parent chunk has no raw object of
                 # its own — record a pass-through
                 return ("p", None, None, data)
+            if plan.hop == "x":
+                # lossless: the stored truth is the child's own bytes
+                with span("chunk.xdelta", cat="store", n=n):
+                    pos, vals = bitpattern_delta_sparse(
+                        data, pbytes, plan.dtype.itemsize)
+                    blob = _XDELTA_CODEC.encode_pairs(pos, vals)
+                if len(blob) < n:
+                    return ("x", str(vals.dtype), blob, data)
+                return ("c", ckey, data, data)
             child = np.frombuffer(data, dtype=np.float32)
             parent = np.frombuffer(pbytes, dtype=np.float32)
             with span("chunk.quantize", cat="store", n=n):
@@ -1153,16 +1178,26 @@ class ArtifactStore:
                     self.io_stats["chunks_deduped"] += 1
                 else:
                     self.io_stats["chunk_bytes_written"] += n
-        elif tag == "b":
+        elif tag in ("b", "x"):
             with span("chunk.write", cat="store", n=n):
                 bkey = self.cas.put_bytes(payload)
             taken.append(bkey)
-            qdtype, codname = meta
-            plan.items[idx] = {"b": bkey, "n": n, "q": qdtype}
-            if codname != self.codec:
-                plan.items[idx]["k"] = codname
+            if tag == "x":
+                # a lossless bit-pattern hop: ``q`` is the unsigned width
+                # of the differences, ``x`` marks the hop's kind
+                plan.items[idx] = {"b": bkey, "n": n, "q": meta,
+                                   "k": _XDELTA_CODEC.name, "x": 1}
+            else:
+                qdtype, codname = meta
+                plan.items[idx] = {"b": bkey, "n": n, "q": qdtype}
+                if codname != self.codec:
+                    plan.items[idx]["k"] = codname
             with self._lock:
-                self.io_stats["chunk_delta_blobs"] += 1
+                if tag == "x":
+                    self.io_stats["chunk_xdelta_blobs"] += 1
+                    self.io_stats["chunk_xdelta_bytes"] += len(payload)
+                else:
+                    self.io_stats["chunk_delta_blobs"] += 1
                 self.io_stats["chunk_bytes_written"] += len(payload)
         else:
             plan.items[idx] = {"p": 1, "n": n}
@@ -1189,13 +1224,21 @@ class ArtifactStore:
                 return chain
             cur_ref, cur_key = e["parent_ref"], e["parent_key"]
 
-    def _chunk_value(self, chain: List[Dict[str, Any]], idx: int) -> bytes:
-        """Raw truth bytes of chunk ``idx`` of ``chain[0]``'s tensor.
+    def _chunk_value(self, chain: List[Dict[str, Any]], idx: int,
+                     out: Optional[np.ndarray] = None) -> bytes:
+        """Raw truth bytes of chunk ``idx`` of ``chain[0]``'s tensor (a
+        ``bytearray`` after lossless hops). Lossless hops are applied in
+        place in ``out`` (the chunk's bytes of the destination, as uint8)
+        when given, and ``out`` is returned.
 
         Walks down the chain until a raw ``c`` item, then applies the
-        recorded per-chunk dequant hops back up (``p`` items copy through).
-        Every object is read before the first hop is decoded, so one
-        ``chunk.read`` and one ``chunk.decode`` span cover the chunk.
+        recorded per-chunk hops back up (``p`` items copy through): dequant
+        for float32, the bit-pattern add for a lossless ``x`` hop, whose
+        positions and differences go straight onto a copy of the parent's
+        bits. A chunk's hops are all of one kind, since a grid is inherited
+        only by a leaf of the same dtype. Every object is read before the
+        first hop is decoded, so one ``chunk.read`` and one ``chunk.decode``
+        span cover the chunk.
         Chunk reads bypass the mmap pool: checkout of a huge tensor must
         not charge mapped pages to the process RSS high-water mark."""
         level = 0
@@ -1219,7 +1262,24 @@ class ArtifactStore:
             self.io_stats["chunks_read"] += 1
         if not hops:
             return base
-        with span("chunk.decode", cat="store", n=nbytes):
+        if hops[0][1].get("x"):
+            with span("chunk.decode", cat="store", n=nbytes, kind="xdelta"):
+                qdt = np.dtype(hops[0][1]["q"])
+                if out is None:
+                    out = bytearray(base)
+                    bits = np.frombuffer(out, dtype=qdt)
+                else:
+                    out[:] = np.frombuffer(base, dtype=np.uint8)
+                    bits = out.view(qdt)
+                for (_, item), blob in zip(hops, blobs):
+                    pos, vals = _XDELTA_CODEC.decode_pairs(blob, item["q"])
+                    # positions are unique within a hop, so a fancy-indexed
+                    # add is exact; unsigned, it wraps mod 2^width
+                    bits[pos] += vals
+                with self._lock:
+                    self.io_stats["chain_hops"] += len(hops)
+                return out
+        with span("chunk.decode", cat="store", n=nbytes, kind="dequant"):
             value = np.frombuffer(base, dtype=np.float32)
             for (e, item), blob in zip(hops, blobs):
                 # per-item ``k`` overrides the entry codec (density-adaptive
@@ -1243,16 +1303,31 @@ class ArtifactStore:
 
         def fill(idx: int) -> None:
             off, n = spans[idx]
-            flat[off:off + n] = np.frombuffer(
-                self._chunk_value(chain, idx), dtype=np.uint8)
+            dst = flat[off:off + n]
+            got = self._chunk_value(chain, idx, out=dst)
+            if got is not dst:
+                dst[:] = np.frombuffer(got, dtype=np.uint8)
 
-        # Fan out only from a non-pool thread (pool workers must never
-        # submit back into the shared pool — materialize_artifact already
-        # parallelizes across params); writes hit disjoint slices.
-        on_pool = threading.current_thread().name.startswith(
-            "artifact-store-io")
-        if not on_pool and self.io_workers > 1 and len(spans) > 2:
-            list(self._executor().map(propagate(fill), range(len(spans))))
+        # Chunks fan out over the shared pool (writes hit disjoint slices).
+        # The calling thread runs every chunk no worker has started and
+        # waits only on running ones, which never wait themselves: no
+        # deadlock on a pool worker (materialize_artifact hands each leaf
+        # to one), and a large leaf is not left to one thread.
+        if self.io_workers > 1 and len(spans) > 2:
+            work = propagate(fill)
+            futs = [self._executor().submit(work, i)
+                    for i in range(len(spans))]
+            try:
+                for i, fut in enumerate(futs):
+                    if fut.cancel():
+                        work(i)
+                    else:
+                        fut.result()
+            except BaseException:
+                for fut in futs:
+                    fut.cancel()
+                futures.wait(futs)
+                raise
         else:
             for i in range(len(spans)):
                 fill(i)

@@ -101,27 +101,43 @@ class ZlibCodec(Codec):
 
 class SparseCodec(Codec):
     """Beyond-paper: store (index-delta varint-ish uint32, value int32) of
-    nonzeros, then zlib. Wins over RLE/LZMA below ~5% density."""
+    nonzeros, then zlib at ``level``. Wins over RLE/LZMA below ~5% density.
+    Inflate does not depend on the level, so readers need not know it."""
 
     name = "sparse"
 
+    def __init__(self, level: int = 6) -> None:
+        self.level = level
+
     def encode(self, arr: np.ndarray) -> bytes:
         flat = np.ascontiguousarray(arr).ravel()
-        idx = np.flatnonzero(flat).astype(np.uint32)
-        vals = flat[idx]
-        idx_delta = np.diff(idx, prepend=np.uint32(0)).astype(np.uint32)
-        payload = struct.pack("<I", idx.size) + idx_delta.tobytes() + vals.tobytes()
-        return zlib.compress(payload, 6)
+        idx = np.flatnonzero(flat)
+        return self.encode_pairs(idx, flat[idx])
 
-    def decode(self, data: bytes, n: int, dtype: str = "int32") -> np.ndarray:
+    def encode_pairs(self, idx: np.ndarray, vals: np.ndarray) -> bytes:
+        """The layout of ``encode``, from ascending positions and the
+        values there: what a caller that found the nonzeros itself
+        stores without building the dense array."""
+        idx = np.asarray(idx, dtype=np.uint32)
+        idx_delta = np.diff(idx, prepend=np.uint32(0)).astype(np.uint32)
+        payload = (struct.pack("<I", idx.size) + idx_delta.tobytes()
+                   + np.ascontiguousarray(vals).tobytes())
+        return zlib.compress(payload, self.level)
+
+    def decode_pairs(self, data: bytes, dtype: str = "int32"):
+        """``(positions as int64, values)`` of a blob, without the dense
+        array."""
         dt = np.dtype(dtype)
         payload = zlib.decompress(data)
         (k,) = struct.unpack("<I", payload[:4])
-        idx_delta = np.frombuffer(payload[4:4 + 4 * k], dtype=np.uint32)
-        vals = np.frombuffer(payload[4 + 4 * k:4 + 4 * k + dt.itemsize * k],
-                             dtype=dt)
-        out = np.zeros(n, dtype=dt)
-        out[np.cumsum(idx_delta.astype(np.int64))] = vals
+        idx_delta = np.frombuffer(payload, dtype=np.uint32, count=k, offset=4)
+        vals = np.frombuffer(payload, dtype=dt, count=k, offset=4 + 4 * k)
+        return np.cumsum(idx_delta, dtype=np.int64), vals
+
+    def decode(self, data: bytes, n: int, dtype: str = "int32") -> np.ndarray:
+        idx, vals = self.decode_pairs(data, dtype)
+        out = np.zeros(n, dtype=vals.dtype)
+        out[idx] = vals
         return out
 
 
@@ -188,6 +204,19 @@ def bitpattern_apply(parent: np.ndarray, delta: np.ndarray,
     return child.view(dt).reshape(shape)
 
 
+def bitpattern_delta_sparse(child, parent, itemsize: int):
+    """:func:`bitpattern_delta` of two raw buffers of elements of
+    ``itemsize`` bytes, in sparse form: the positions whose bits differ
+    and the differences there (mod 2^width). Adding the differences to
+    the parent's bits there, in the same unsigned width, gives the child
+    bit for bit."""
+    ud = _bitwidth_dtype(itemsize)
+    cv = np.frombuffer(child, dtype=ud)
+    pv = np.frombuffer(parent, dtype=ud)
+    idx = np.flatnonzero(cv != pv)
+    return idx, cv[idx] - pv[idx]
+
+
 CODECS: Dict[str, Codec] = {
     "raw": RawCodec(),
     "rle": RLECodec(),
@@ -225,9 +254,10 @@ def get_codec(name: str, preset: int = None) -> Codec:
     """Codec by name, optionally tuned.
 
     ``preset`` selects the LZMA preset (0 fastest … 9 strongest) or the
-    zlib level. Decoding is container-self-describing for both, so the
-    manifest only records the codec *name* — readers never need to know the
-    preset the writer used. Tuned instances are cached (codec objects are
+    zlib level (of ``zlib`` and ``sparse``). Decoding is
+    container-self-describing for all three, so the manifest only records
+    the codec *name* — readers never need to know the preset the writer
+    used. Tuned instances are cached (codec objects are
     stateless)."""
     if preset is None:
         return CODECS[name]
@@ -237,6 +267,8 @@ def get_codec(name: str, preset: int = None) -> Codec:
             _TUNED[key] = LZMACodec(preset=preset)
         elif name == "zlib":
             _TUNED[key] = ZlibCodec(level=preset)
+        elif name == "sparse":
+            _TUNED[key] = SparseCodec(level=preset)
         else:
             _TUNED[key] = CODECS[name]  # preset is a no-op for this codec
     return _TUNED[key]

@@ -343,6 +343,84 @@ def test_chunk_stream_commit_is_byte_identical(tmp_path, io_workers, window):
             store.materialize_param(got, k))
 
 
+@pytest.mark.parametrize("io_workers", [2, 16])
+def test_checkout_spreads_a_leafs_chunks_over_the_pool(tmp_path, io_workers):
+    """``materialize_artifact`` hands each leaf to one pool worker, which
+    spreads the leaf's chunks over the pool, running itself those no other
+    worker has started: with fewer workers than leaves, or more workers
+    than cores, and threads switching every 10 us, the checkout ends and
+    every leaf is bit for bit the serial one; with workers to spare, a
+    leaf's chunks ran on more than one thread."""
+    store, refs, child = _stream_chain(tmp_path / "repo")
+    ref = store.commit_artifact("c3", ModelArtifact(_stream_graph(), child),
+                                parent_ref=refs[-1])
+    serial = ArtifactStore(root=str(tmp_path / "repo"), io_workers=1,
+                           **CHUNK_KW)
+    want = {k: serial.materialize_param(ref, k) for k in CHUNKED}
+    got = {}
+
+    def checkout():
+        wide = ArtifactStore(root=str(tmp_path / "repo"),
+                             io_workers=io_workers, **CHUNK_KW)
+        with tracing():
+            got.update(wide.materialize_artifact(ref).params)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    reset_trace()
+    try:
+        t = threading.Thread(target=checkout)
+        t.start()
+        t.join(timeout=60)
+        assert not t.is_alive(), "checkout did not finish"
+        evs = [e for e in export_chrome_trace()["traceEvents"]
+               if e.get("ph") == "X"]
+    finally:
+        sys.setswitchinterval(interval)
+        reset_trace()
+    for k in CHUNKED:
+        assert got[k].tobytes() == want[k].tobytes(), k
+    params = {e["args"]["span_id"]: e["args"]["key"] for e in evs
+              if e["name"] == "checkout.param"}
+    threads = {}
+    for e in evs:
+        if e["name"] == "chunk.read" and e["args"]["parent_id"] in params:
+            threads.setdefault(params[e["args"]["parent_id"]],
+                               set()).add(e["tid"])
+    if io_workers > len(CHUNKED):
+        assert max(len(t) for t in threads.values()) > 1, threads
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_checkout_of_a_missing_chunk_raises_and_pool_survives(tmp_path,
+                                                              batched):
+    """A chunk object gone from the CAS fails the leaf's checkout on the
+    calling thread, whether the caller is off the pool (a direct
+    ``materialize_param``) or a pool worker (``materialize_artifact``);
+    the leaf's other chunks are cancelled or waited for, and the same
+    store then checks out an intact leaf."""
+    store = ArtifactStore(root=str(tmp_path), pack_threshold=1024,
+                          io_workers=4, **CHUNK_KW)
+    art, _ = big_artifact()
+    _, w2 = big_artifact(seed=5)
+    g2 = LayerGraph.chain([LayerNode("b2", "linear",
+                                     params={"w": (w2.shape, "float32")})])
+    bad = store.commit_artifact("m", art)
+    good = store.commit_artifact("g", ModelArtifact(g2, {"b2/w": w2}))
+    chunks_ = store.get_manifest(bad)["params"]["big/w"]["chunks"]
+    assert len(chunks_) > 2
+    os.remove(os.path.join(str(tmp_path), "objects",
+                           chunks_[len(chunks_) // 2]["c"]))
+    store = ArtifactStore(root=str(tmp_path), pack_threshold=1024,
+                          io_workers=4, **CHUNK_KW)
+    with pytest.raises(KeyError):
+        if batched:
+            store.materialize_artifact(bad)
+        else:
+            store.materialize_param(bad, "big/w")
+    assert store.materialize_param(good, "b2/w").tobytes() == w2.tobytes()
+
+
 class _Ledger:
     """Chunk bytes read from the sources and not yet consumed, the consume
     marker being the chunk's ``put_bytes`` under its content key."""
@@ -655,3 +733,192 @@ def test_http_parallel_ranged_read_matches_single_stream(tmp_path):
     finally:
         server.shutdown()
         server.server_close()
+
+
+# ---------------------------------------------------------------------------
+# lossless chunk hops: non-float32 leaves against their parent's truth
+# ---------------------------------------------------------------------------
+
+def _ulp_finetune(w, seed, density=0.1):
+    """G2-style: a ``density`` share of the elements moves by a few ulps."""
+    import ml_dtypes  # noqa: F401  (registers bfloat16 with NumPy)
+    rng = np.random.default_rng(seed)
+    bits = w.view(np.uint16).copy()
+    mask = rng.random(bits.shape) < density
+    bits[mask] += rng.integers(1, 3, int(mask.sum())).astype(np.uint16)
+    return bits.view(w.dtype)
+
+
+def _specials(w, seed):
+    """Sparse bit patterns no arithmetic produces from normal values:
+    NaNs with payloads, both infinities, -0 and subnormals."""
+    rng = np.random.default_rng(seed)
+    bits = w.view(np.uint16).copy().reshape(-1)
+    pos = rng.choice(bits.size, 600, replace=False)
+    exp = {"bfloat16": 0x7F80, "float16": 0x7C00}[str(w.dtype)]
+    patterns = np.array([exp | 1, exp | 0x8003, exp, 0x8000 | exp, 0x8000,
+                         0x0001, 0x8002, exp - 1], dtype=np.uint16)
+    bits[pos] = patterns[np.arange(pos.size) % patterns.size]
+    return bits.view(w.dtype).reshape(w.shape)
+
+
+def _item_kind(item):
+    return "x" if item.get("x") else next(k for k in ("c", "b", "p")
+                                          if k in item)
+
+
+def _assert_checks_out(root, ref, want, **kw):
+    """A fresh store's every checkout path gives ``want`` bit for bit, and
+    the entry's hash is ``tensor_hash`` of it."""
+    store = ArtifactStore(root=str(root), **dict(CHUNK_KW, **kw))
+    e = store.get_manifest(ref)["params"]["big/w"]
+    assert e["hash"] == tensor_hash(want)
+    raw = want.tobytes()
+    got = store.materialize_param(ref, "big/w")
+    assert got.dtype == want.dtype and got.tobytes() == raw
+    assert b"".join(bytes(d) for _, d in store.stream_param(ref, "big/w")) \
+        == raw
+    assert store.materialize_param_range(ref, "big/w", 1000, 90_001) == \
+        raw[1000:90_001]
+
+
+@pytest.mark.parametrize("change", ["finetune", "specials"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float16"])
+def test_lossless_chunk_hop_round_trips(tmp_path, dtype, change):
+    """A 16-bit leaf committed against its parent stores each changed
+    chunk as a lossless bit-pattern hop (``x``), and every checkout gives
+    the child's own bits, whatever they encode."""
+    store = ArtifactStore(root=str(tmp_path), **CHUNK_KW)
+    art, w = big_artifact(dtype=dtype)
+    r1 = store.commit_artifact("m", art)
+    child = (_ulp_finetune(w, 1) if change == "finetune"
+             else _specials(w, 1))
+    r2 = store.commit_artifact("m", ModelArtifact(art.graph,
+                                                  {"big/w": child}),
+                               parent_ref=r1)
+    e = store.get_manifest(r2)["params"]["big/w"]
+    assert e["parent_ref"] == r1
+    assert {_item_kind(it) for it in e["chunks"]} == {"x"}
+    for it in e["chunks"]:
+        assert (it["q"], it["k"], it["x"]) == ("uint16", "sparse", 1)
+    assert store.io_stats["chunk_xdelta_blobs"] == len(e["chunks"])
+    assert 0 < store.io_stats["chunk_xdelta_bytes"] < 0.5 * child.nbytes
+    assert store.io_stats["chunk_delta_blobs"] == 0
+    _assert_checks_out(tmp_path, r2, child)
+
+
+def test_lossless_chunk_hop_chain_and_depth_reset(tmp_path):
+    """Three hops decode exactly, each from a fresh store, and the chain
+    depth knob resets the fourth commit to raw chunks."""
+    store = ArtifactStore(root=str(tmp_path), max_chain_depth=3, **CHUNK_KW)
+    art, w = big_artifact(dtype="bfloat16")
+    refs, values = [store.commit_artifact("m", art)], [w]
+    for k in range(1, 5):
+        values.append(_ulp_finetune(values[-1], k))
+        refs.append(store.commit_artifact(
+            "m", ModelArtifact(art.graph, {"big/w": values[-1]}),
+            parent_ref=refs[-1]))
+    for k in range(1, 4):
+        e = store.get_manifest(refs[k])["params"]["big/w"]
+        assert {_item_kind(it) for it in e["chunks"]} == {"x"}
+        assert store.get_manifest(refs[k])["depth"] == k
+        _assert_checks_out(tmp_path, refs[k], values[k], max_chain_depth=3)
+    e = store.get_manifest(refs[4])["params"]["big/w"]
+    assert {_item_kind(it) for it in e["chunks"]} == {"c"}
+    assert "parent_ref" not in e and store.get_manifest(refs[4])["depth"] == 0
+    _assert_checks_out(tmp_path, refs[4], values[4], max_chain_depth=3)
+
+
+def test_lossless_chunk_hop_not_smaller_stays_raw(tmp_path):
+    """Where the hop's blob is no smaller than the chunk (every element
+    rewritten with incompressible bits), the chunk is stored raw, as a
+    float32 chunk would be."""
+    store = ArtifactStore(root=str(tmp_path), **CHUNK_KW)
+    art, w = big_artifact(dtype="bfloat16")
+
+    def noise(seed):
+        rng = np.random.default_rng(seed)
+        return rng.integers(0, 2 ** 16, w.shape, dtype=np.uint16).view(
+            w.dtype)
+
+    r1 = store.commit_artifact("m", ModelArtifact(art.graph,
+                                                  {"big/w": noise(4)}))
+    fresh = noise(5)
+    r2 = store.commit_artifact("m", ModelArtifact(art.graph,
+                                                  {"big/w": fresh}),
+                               parent_ref=r1)
+    e = store.get_manifest(r2)["params"]["big/w"]
+    assert {_item_kind(it) for it in e["chunks"]} == {"c"}
+    assert store.io_stats["chunk_xdelta_blobs"] == 0
+    _assert_checks_out(tmp_path, r2, fresh)
+
+
+def test_lossless_chunk_hops_walk_gc_and_sync(tmp_path):
+    """The hops' blobs are ``b`` objects to the manifest walk, GC and sync:
+    listed, kept while the child lives, pushed and pulled into a fresh
+    store that checks the child out bit for bit; gone once released."""
+    from repro.remote.sync import pull, push
+    from repro.store.manifest_walk import parse_manifest
+    g1, store = _lineage(tmp_path, "src", **CHUNK_KW)
+    art, w = big_artifact(dtype="bfloat16")
+    g1.add_node(art, "m")
+    child = _ulp_finetune(w, 2)
+    g1.add_node(ModelArtifact(art.graph, {"big/w": child}), "m2")
+    g1.add_version_edge("m", "m2")
+    ref = g1.nodes["m2"].artifact_ref
+    blobs = [it["b"] for it in
+             store.get_manifest(ref)["params"]["big/w"]["chunks"]
+             if it.get("x")]
+    assert blobs
+    assert set(blobs) <= set(parse_manifest(store.cas.get_bytes(ref))
+                             .objects)
+    store.cas.gc()
+    assert all(store.cas.has(b) for b in blobs)
+    report = store.fsck([g1.nodes["m"].artifact_ref, ref])
+    assert report["ok"], report
+    remote = LocalTransport(str(tmp_path / "remote"))
+    push(g1, remote)
+    g2, dst = _lineage(tmp_path, "dst", **CHUNK_KW)
+    pull(g2, remote)
+    assert all(dst.cas.has(b) for b in blobs)
+    got = np.asarray(dst.load_artifact(g2.nodes["m2"].artifact_ref)
+                     .params["big/w"])
+    assert got.tobytes() == child.tobytes()
+    store.release(ref)
+    store.cas.gc()
+    assert not any(store.cas.has(b) for b in blobs)
+
+
+def test_float32_chunk_items_keep_their_kinds(tmp_path):
+    """A float32 lineage commit stores quantized hops, raw chunks and
+    pass-throughs as before: no item carries the lossless marker."""
+    store, refs, child = _stream_chain(tmp_path)
+    store.reset_io_stats()
+    ref = store.commit_artifact("c3", ModelArtifact(_stream_graph(), child),
+                                parent_ref=refs[-1])
+    shapes = {tuple(sorted(it))
+              for r in refs + [ref]
+              for k in CHUNKED
+              for it in store.get_manifest(r)["params"][k]["chunks"]}
+    assert shapes <= {("c", "n"), ("b", "n", "q"), ("b", "k", "n", "q"),
+                      ("n", "p")}
+    assert {("c", "n"), ("n", "p")} < shapes
+    assert store.io_stats["chunk_delta_blobs"] > 0
+    assert store.io_stats["chunk_xdelta_blobs"] == 0
+
+
+def test_exact_tier_keeps_bf16_changed_chunks_raw(tmp_path):
+    """The exact checkpoint tier's chunked leaves take no hop: a changed
+    bf16 chunk is stored raw, so the entry's truth is the live value."""
+    store = ArtifactStore(root=str(tmp_path), **CHUNK_KW)
+    art, w = big_artifact(dtype="bfloat16")
+    r0 = store.commit_step("s0", {"big/w": w},
+                           graph_json=art.graph.to_json())
+    child = _ulp_finetune(w, 3)
+    r1 = store.commit_step("s1", {"big/w": child}, parent_ref=r0,
+                           tier="exact")
+    e = store.get_manifest(r1)["params"]["big/w"]
+    assert e["kind"] == "chunked"
+    assert {_item_kind(it) for it in e["chunks"]} == {"c"}
+    assert store.io_stats["chunk_xdelta_blobs"] == 0
+    assert store.materialize_param(r1, "big/w").tobytes() == child.tobytes()

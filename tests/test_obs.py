@@ -433,10 +433,59 @@ def test_chunk_stage_spans_once_per_chunk_and_stage(tmp_path):
     evs = _span_events()
     chunk_evs = [e for e in evs if e["name"].startswith("chunk.")]
     assert len(chunk_evs) == 2 * n
-    # the fan-out ran on the pool, and propagate() kept the parent
-    assert threading.get_ident() not in {e["tid"] for e in chunk_evs}
+    # the fan-out ran on the store's pool and on the caller, which runs the
+    # chunks no worker has started; propagate() kept the parent on each
+    pool = {t.ident for t in threading.enumerate()
+            if t.name.startswith("artifact-store-io")}
+    assert pool
+    assert {e["tid"] for e in chunk_evs} <= pool | {threading.get_ident()}
     assert set(_roots(evs).values()) == {"checkout.param"}
     assert np.array_equal(got.view(np.uint32), direct.view(np.uint32))
+
+
+def _parent_names(evs):
+    by_id = {e["args"]["span_id"]: e for e in evs}
+    return {e["args"]["span_id"]: by_id[e["args"]["parent_id"]]["name"]
+            for e in evs if e["args"]["parent_id"] in by_id}
+
+
+def test_lossless_chunk_hop_spans_and_counters(tmp_path):
+    """A bf16 chunked commit against its parent opens one ``chunk.xdelta``
+    per changed chunk under the commit's ``commit.chunk_stream``, whose
+    args count them, as ``io_stats`` does with the bytes they wrote; the
+    checkout decodes each inside ``chunk.decode``, marked as such."""
+    from test_chunks import CHUNK_KW, _ulp_finetune, big_artifact
+
+    store = ArtifactStore(root=str(tmp_path), io_workers=4, **CHUNK_KW)
+    art, w = big_artifact(dtype="bfloat16")
+    r1 = store.commit_artifact("m1", art)
+    child = ModelArtifact(art.graph, {"big/w": _ulp_finetune(w, 1)})
+    store.reset_io_stats()
+    with tracing():
+        r2 = store.commit_artifact("m2", child, parent_ref=r1)
+    evs = _span_events()
+    items = store.get_manifest(r2)["params"]["big/w"]["chunks"]
+    n = len(items)
+    assert n > 2 and all(it.get("x") for it in items)
+    xd = [e for e in evs if e["name"] == "chunk.xdelta"]
+    assert len(xd) == n and all(e["args"]["n"] > 0 for e in xd)
+    parents = _parent_names(evs)
+    assert {parents[e["args"]["span_id"]] for e in xd} == {
+        "commit.chunk_stream"}
+    (stream,) = [e for e in evs if e["name"] == "commit.chunk_stream"]
+    assert stream["args"]["xdelta_chunks"] == n
+    assert _count(evs, "chunk.quantize") == _count(evs, "chunk.encode") == 0
+    assert store.io_stats["chunk_xdelta_blobs"] == n
+    written = sum(store.cas.size(it["b"]) for it in items)
+    assert store.io_stats["chunk_xdelta_bytes"] == written > 0
+
+    reset_trace()
+    store = ArtifactStore(root=str(tmp_path), io_workers=4, **CHUNK_KW)
+    with tracing():
+        store.materialize_artifact(r2)
+    dec = [e for e in _span_events() if e["name"] == "chunk.decode"]
+    assert len(dec) == n
+    assert {e["args"]["kind"] for e in dec} == {"xdelta"}
 
 
 def test_checkpoint_save_spans_transfer_and_wait(tmp_path):
