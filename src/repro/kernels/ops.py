@@ -24,6 +24,7 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.kernels import ref as _ref
 from repro.kernels.chain_apply import chain_apply_2d, chain_apply_ref
@@ -262,6 +263,110 @@ def fingerprint(x, backend: Optional[str] = None) -> int:
         x, interpret=_count("fingerprint", backend)))
 
 
+# ---------------------------------------------------------------------------
+# device -> host copy
+# ---------------------------------------------------------------------------
+
+#: Traces of the copy's device view: one per (shape, dtype) in a process.
+VIEW_TRACES = REGISTRY.group(
+    "mgit_d2h_view", keys=("traces",),
+    help="Traces of the device program that views a leaf as uint32 words")
+
+#: Bytes of the leaf the view packs at a time: its temporaries stay this
+#: small however large the leaf.
+VIEW_BLOCK_BYTES = 8 << 20
+
+
+def _words(part, width: int):
+    """Each run of ``32 // width`` adjacent elements along the last axis of
+    ``part`` as one uint32 word, the first in the low bits: the row-major
+    bytes of ``part``, read as words, flattened."""
+    pack = 32 // width
+    bits = lax.bitcast_convert_type(part, jnp.dtype(f"uint{width}"))
+    bits = bits.reshape(-1, bits.shape[-1])
+    out = bits[:, 0::pack].astype(jnp.uint32)
+    for j in range(1, pack):
+        out = out | (bits[:, j::pack].astype(jnp.uint32) << (width * j))
+    return out.reshape(-1)
+
+
+@jax.jit
+def _u32_view_dev(x):
+    VIEW_TRACES.inc("traces")  # runs while tracing, not per call
+    width = 8 * x.dtype.itemsize
+    if x.ndim == 1:
+        x = x.reshape(1, -1)
+    # packing a large leaf in one go makes XLA stage whole copies of it;
+    # a loop over blocks of its first axis writes each block's words in
+    # place. A 2-D leaf's blocks are whole tiles of rows.
+    lead, slab = x.shape[0], math.prod(x.shape[1:])
+    align = 32 if x.ndim == 2 else 1
+    step = VIEW_BLOCK_BYTES // (slab * x.dtype.itemsize) // align * align
+    step = min(lead, max(align, step))
+    n_steps, tail = divmod(lead, step)
+    block_words = step * slab * width // 32
+
+    def block(i, out):
+        part = lax.dynamic_slice_in_dim(x, i * step, step)
+        return lax.dynamic_update_slice_in_dim(
+            out, _words(part, width), i * block_words, 0)
+
+    out = lax.fori_loop(0, n_steps, block,
+                        jnp.zeros((x.size * width // 32,), jnp.uint32))
+    if tail:
+        out = lax.dynamic_update_slice_in_dim(
+            out, _words(x[n_steps * step:], width), n_steps * block_words, 0)
+    return out
+
+
+def _bits(dtype) -> int:
+    """Bits of one element of a float or integer dtype, else 0."""
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.finfo(dtype).bits
+    if jnp.issubdtype(dtype, jnp.integer):
+        return jnp.iinfo(dtype).bits
+    return 0
+
+
+def u32_view(x) -> Optional[np.ndarray]:
+    """``x`` on the host, copied as a 1-D array of uint32 words.
+
+    On a TPU a leaf's device layout is tiled, and a 16- or 8-bit leaf's
+    tiles pack rows in pairs or quads, so a plain ``np.asarray`` has the
+    runtime untile it on one host thread. One device pass packs each run
+    of adjacent elements of a row into a word, into a 1-D array whose
+    tiles are its row-major bytes; the host then only reinterprets them.
+    The result is ``np.asarray(x)`` bit for bit. None where the elements
+    do not pack whole into words: 64-bit or sub-byte dtypes, bool,
+    complex, scalars, and a last axis the packing does not divide."""
+    dtype = np.dtype(x.dtype)
+    pack = 4 // dtype.itemsize if dtype.itemsize in (1, 2, 4) else 0
+    if (not pack or _bits(dtype) != 8 * dtype.itemsize or x.ndim == 0
+            or x.size == 0 or x.shape[-1] % pack):
+        return None
+    # the view's device buffer is dropped as soon as its words are here
+    words = np.asarray(_u32_view_dev(x))
+    return words.view(dtype).reshape(x.shape)
+
+
+def _tiled(x) -> bool:
+    """Whether ``x`` lives on one device whose layout is not the host's."""
+    devices = x.devices()
+    return len(devices) == 1 and next(iter(devices)).platform != "cpu"
+
+
+def host_copy(x) -> Tuple[np.ndarray, str]:
+    """``x`` as a row-major host array, and how it was copied: ``"u32"``
+    through :func:`u32_view` for a ``jax.Array`` on one accelerator, else
+    ``"asis"`` by ``np.asarray`` (NumPy and CPU arrays, leaves that do not
+    pack into words)."""
+    if isinstance(x, jax.Array) and _tiled(x):
+        out = u32_view(x)
+        if out is not None:
+            return out, "u32"
+    return np.asarray(x), "asis"
+
+
 __all__ = ["delta_quantize", "dequant_apply", "chain_apply", "snapshot_fused",
            "fingerprint", "fold_fingerprint", "default_backend",
-           "DISPATCHES"]
+           "host_copy", "u32_view", "DISPATCHES", "VIEW_TRACES"]

@@ -358,7 +358,8 @@ class ArtifactStore:
                   "step_commits", "step_leaves_copied", "step_leaves_delta",
                   "step_leaves_xdelta", "step_leaves_full",
                   "chunk_head_waits", "chunk_window_stalls",
-                  "chunk_xdelta_blobs", "chunk_xdelta_bytes"),
+                  "chunk_xdelta_blobs", "chunk_xdelta_bytes",
+                  "d2h_view_leaves", "d2h_view_bytes"),
             help="ArtifactStore I/O accounting")
         self._lock = threading.RLock()   # manifests dict + counters
         self._stats_path = (os.path.join(root, "store_stats.json")
@@ -563,14 +564,15 @@ class ArtifactStore:
 
         backend = self._kernel_backend()
         host = backend == "ref"
+        copied: Dict[str, np.ndarray] = {}  # child leaves off the device
 
         def process(pair):
             pkey, ckey = pair
             p1 = np.asarray(pvals[pkey])
             value = child.params[ckey]
-            with span("commit.d2h", cat="store", key=ckey,
-                      n=getattr(value, "nbytes", 0)):
-                p2 = np.asarray(value)
+            p2 = self._d2h(ckey, value, getattr(value, "nbytes", 0))
+            if p2 is not value:
+                copied[ckey] = p2
             if p1.size == 0:
                 return None
             with span("commit.quantize", cat="store", key=ckey):
@@ -608,6 +610,8 @@ class ArtifactStore:
                                                      pairs))
             else:
                 produced = [process(p) for p in pairs]
+        if copied:  # later stages read the host copies, not the device
+            child = child.replace_params(copied)
 
         candidates: Dict[str, ParamDelta] = {}
         recon_params: Dict[str, np.ndarray] = {}
@@ -983,9 +987,31 @@ class ArtifactStore:
                     nb = int(np.asarray(value).nbytes)
                 if nb < self.chunk_threshold:
                     continue
-            with span("commit.d2h", cat="store", key=key, n=int(nb)):
-                out[key] = chunklib.as_source(value)
+            out[key] = chunklib.as_source(self._d2h(key, value, nb))
         return out
+
+    def _d2h(self, key: str, value, nbytes: int):
+        """``value`` on the host, under the leaf's one ``commit.d2h`` span.
+
+        A device leaf is copied by ``ops.host_copy``: as uint32 words whose
+        device layout is already their row-major bytes where it packs
+        (span arg ``view`` "u32", counted in ``d2h_view_leaves`` and
+        ``d2h_view_bytes``), else by ``np.asarray`` ("asis"). Host arrays
+        and chunk sources pass through as they are."""
+        with span("commit.d2h", cat="store", key=key, n=int(nbytes)) as sp:
+            view = "asis"
+            if isinstance(value, np.ndarray) or chunklib.is_chunk_source(
+                    value):
+                host = value
+            else:
+                from repro.kernels import ops
+                host, view = ops.host_copy(value)
+            if sp is not None:
+                sp.args["view"] = view
+        if view == "u32":
+            self.io_stats.inc("d2h_view_leaves")
+            self.io_stats.inc("d2h_view_bytes", int(nbytes))
+        return host
 
     def _shard_segments(self, key: str, shape, itemsize: int):
         """Hard chunk-grid boundaries from the mesh sharding spec, or None."""

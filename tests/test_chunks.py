@@ -922,3 +922,127 @@ def test_exact_tier_keeps_bf16_changed_chunks_raw(tmp_path):
     assert {_item_kind(it) for it in e["chunks"]} == {"c"}
     assert store.io_stats["chunk_xdelta_blobs"] == 0
     assert store.materialize_param(r1, "big/w").tobytes() == child.tobytes()
+
+
+# ---------------------------------------------------------------------------
+# device -> host copy through a uint32 view (ops.host_copy)
+# ---------------------------------------------------------------------------
+
+def _special_bits(dtype, size, seed):
+    """``size`` elements of ``dtype``: normal values with NaNs (payloads
+    too), both infinities, -0 and subnormals of either sign mixed in."""
+    import ml_dtypes
+    dt = np.dtype(dtype)
+    width = 8 * dt.itemsize
+    uint = np.dtype(f"uint{width}")
+    rng = np.random.default_rng(seed)
+    bits = rng.standard_normal(size).astype(dt).view(uint)
+    inf = int(np.array(np.inf, dt).view(uint))
+    sign, quiet = 1 << (width - 1), 1 << (ml_dtypes.finfo(dt).nmant - 1)
+    patterns = np.array([inf | 1, inf | quiet, inf | quiet | 3, inf,
+                         sign | inf, sign, 1, sign | 1, quiet - 1,
+                         sign | (2 * quiet - 1)], dtype=uint)
+    pos = rng.choice(size, min(size, 4 * patterns.size), replace=False)
+    bits[pos] = patterns[np.arange(pos.size) % patterns.size]
+    return bits.view(dt)
+
+
+VIEW_CASES = ([(dt, shape, "normal")
+               for dt in ("bfloat16", "float16", "float32", "int8")
+               for shape in ((4096,), (48, 88), (3, 20, 36))]
+              + [("bfloat16", (5, 7), "normal"),      # odd count: no words
+                 ("bfloat16", (40, 64), "specials"),
+                 ("float16", (2, 30, 32), "specials"),
+                 ("float32", (1000,), "specials")])
+
+
+@pytest.mark.parametrize("dtype,shape,content", VIEW_CASES)
+def test_host_copy_u32_view_is_bit_identical(monkeypatch, dtype, shape,
+                                             content):
+    """The uint32 view copies a leaf's bytes exactly as ``np.asarray`` does,
+    whatever they encode; a leaf whose elements do not fill whole words
+    falls back to ``np.asarray``."""
+    import jax.numpy as jnp
+    from repro.kernels import ops
+
+    size = int(np.prod(shape))
+    if content == "specials":
+        want = _special_bits(dtype, size, seed=len(shape)).reshape(shape)
+    else:
+        rng = np.random.default_rng(size)
+        want = (rng.standard_normal(shape) * 50).astype(dtype)
+    x = jnp.asarray(want)
+    raw = np.asarray(x).tobytes()
+    assert raw == want.tobytes()
+    monkeypatch.setattr(ops, "_tiled", lambda a: True)  # as on a TPU
+    got, view = ops.host_copy(x)
+    packs = size % (4 // np.dtype(dtype).itemsize) == 0
+    assert view == ("u32" if packs else "asis")
+    assert (ops.u32_view(x) is None) == (not packs)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert got.tobytes() == raw
+
+
+VIEW_LEAVES = {"a/w": ((264, 300), "bfloat16"), "b/w": ((200, 330), "float16"),
+               "c/w": ((130, 261), "float32")}
+
+
+def _view_artifact(seed):
+    g = LayerGraph.chain([LayerNode(k.split("/")[0], "linear",
+                                    params={"w": spec})
+                          for k, spec in VIEW_LEAVES.items()])
+    rng = np.random.default_rng(seed)
+    return g, {k: rng.standard_normal(s).astype(dt)
+               for k, (s, dt) in VIEW_LEAVES.items()}
+
+
+def test_device_commit_through_u32_view_matches_numpy_commit(tmp_path,
+                                                             monkeypatch):
+    """A derivative committed from device arrays through the uint32 view
+    and the same derivative committed from NumPy arrays store the same
+    manifest and the same chunk objects; only the device commit counts
+    viewed leaves, and a later commit of the same shapes, in a fresh store,
+    traces the view no further time."""
+    import jax.numpy as jnp
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_tiled", lambda a: True)  # as on a TPU
+    graph, base = _view_artifact(0)
+    _, noise = _view_artifact(1)
+    child = {k: (v + noise[k] * 1e-2).astype(v.dtype)
+             for k, v in base.items()}
+
+    def commit(root, params, before=None):
+        store = ArtifactStore(root=str(root), **CHUNK_KW)
+        parent = before or store.commit_artifact("base",
+                                                 ModelArtifact(graph, base))
+        store = ArtifactStore(root=str(root), **CHUNK_KW)
+        ref = store.commit_artifact("child", ModelArtifact(graph, params),
+                                    parent_ref=parent)
+        return store, ref, parent
+
+    traces = ops.VIEW_TRACES["traces"]
+    dev_store, dev_ref, parent = commit(
+        tmp_path / "dev", {k: jnp.asarray(v) for k, v in child.items()})
+    assert 0 < ops.VIEW_TRACES["traces"] - traces <= len(VIEW_LEAVES)
+    np_store, np_ref, _ = commit(tmp_path / "np", child)
+    assert dev_ref == np_ref
+    assert set(dev_store.cas.keys()) == set(np_store.cas.keys())
+    entries = dev_store.get_manifest(dev_ref)["params"]
+    assert all(entries[k]["kind"] == "chunked" for k in VIEW_LEAVES)
+    assert dev_store.io_stats["d2h_view_leaves"] == len(VIEW_LEAVES)
+    assert dev_store.io_stats["d2h_view_bytes"] == sum(
+        v.nbytes for v in child.values())
+    assert np_store.io_stats["d2h_view_leaves"] == 0
+    assert np_store.io_stats["d2h_view_bytes"] == 0
+
+    traces = ops.VIEW_TRACES["traces"]
+    _, noise = _view_artifact(2)
+    again = {k: jnp.asarray((v + noise[k] * 1e-2).astype(v.dtype))
+             for k, v in base.items()}
+    store, ref, _ = commit(tmp_path / "dev", again, before=parent)
+    assert ops.VIEW_TRACES["traces"] == traces
+    assert store.io_stats["d2h_view_leaves"] == len(VIEW_LEAVES)
+    for k in ("a/w", "b/w"):   # 16-bit hops are lossless
+        assert store.materialize_param(ref, k).tobytes() == \
+            np.asarray(again[k]).tobytes()

@@ -93,3 +93,20 @@ def test_kernel_compiles_for_v5e(one_chip, program, shape):
         return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
     compiled = _programs(sds)[program](shape).compile()
     assert "tpu_custom_call" in compiled.as_text()  # the kernel, not XLA
+
+
+@pytest.mark.parametrize("shape,dtype", [(EMBED, BF16), (STACKED, BF16),
+                                         (LEAF, F32), (SMALL, I8)],
+                         ids=["embed_bf16", "stacked_bf16", "leaf_f32",
+                              "small_i8"])
+def test_u32_view_compiles_for_v5e_without_staging_the_leaf(one_chip, shape,
+                                                             dtype):
+    """The device->host copy's word view compiles for a v5e and holds no
+    more than a few row blocks besides its output: a view that staged the
+    whole leaf, as a one-shot pack of a tiled 16-bit array does, would
+    add the leaf's size again to a commit's peak device memory."""
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    mem = ops._u32_view_dev.lower(x).compile().memory_analysis()
+    nbytes = x.size * jnp.dtype(dtype).itemsize
+    assert nbytes <= mem.output_size_in_bytes <= max(nbytes, 4096)  # a tile
+    assert mem.temp_size_in_bytes <= 4 * ops.VIEW_BLOCK_BYTES

@@ -443,6 +443,39 @@ def test_chunk_stage_spans_once_per_chunk_and_stage(tmp_path):
     assert np.array_equal(got.view(np.uint32), direct.view(np.uint32))
 
 
+@pytest.mark.parametrize("on_device", [False, True])
+def test_commit_d2h_spans_carry_view(tmp_path, monkeypatch, on_device):
+    """Every ``commit.d2h`` span names how its leaf came to the host: a
+    device leaf as uint32 words (``u32``), in the chunk layer and in the
+    whole-tensor delta workers alike; a NumPy leaf as it is (``asis``)."""
+    import jax.numpy as jnp
+    from repro.core import LayerGraph, LayerNode
+    from repro.kernels import ops
+    from test_chunks import CHUNK_KW
+
+    monkeypatch.setattr(ops, "_tiled", lambda a: True)  # as on a TPU
+    shapes = {"big/w": (256, 300), "small/w": (24, 40)}
+    graph = LayerGraph.chain([LayerNode(k.split("/")[0], "linear",
+                                        params={"w": (s, "bfloat16")})
+                              for k, s in shapes.items()])
+    rng = np.random.default_rng(0)
+    base = {k: rng.standard_normal(s).astype("bfloat16")
+            for k, s in shapes.items()}
+    child = {k: (v + rng.standard_normal(v.shape) * 0.1).astype(v.dtype)
+             for k, v in base.items()}
+    if on_device:
+        child = {k: jnp.asarray(v) for k, v in child.items()}
+    store = ArtifactStore(root=str(tmp_path), io_workers=4, **CHUNK_KW)
+    r1 = store.commit_artifact("m1", ModelArtifact(graph, base))
+    with tracing():
+        store.commit_artifact("m2", ModelArtifact(graph, child),
+                              parent_ref=r1)
+    d2h = {e["args"]["key"]: e["args"]["view"] for e in _span_events()
+           if e["name"] == "commit.d2h"}
+    view = "u32" if on_device else "asis"
+    assert d2h == {"big/w": view, "small/w": view}
+
+
 def _parent_names(evs):
     by_id = {e["args"]["span_id"]: e for e in evs}
     return {e["args"]["span_id"]: by_id[e["args"]["parent_id"]]["name"]
