@@ -61,30 +61,6 @@ def run_store_backed(scales=(1, 2)) -> List[Dict]:
     return rows
 
 
-def run_commit_engines(scale: int = 1) -> List[Dict]:
-    """Store-backed insertion through the serial vs pipelined commit engine
-    (DESIGN.md §10.1) — same pool, same graph work, only the storage commit
-    path differs."""
-    rows = []
-    pool, _, _ = g2_adaptation(scale=scale)
-    for pipelined in (False, True):
-        with tempfile.TemporaryDirectory() as tmp:
-            store = ArtifactStore(root=tmp, t_thr=float("inf"),
-                                  pipelined=pipelined,
-                                  fold_enabled=pipelined)
-            g = LineageGraph(path=tmp, store=store)
-            t0 = time.perf_counter()
-            for name, artifact in pool:
-                auto_insert(g, artifact, name)
-            dt = time.perf_counter() - t0
-            rows.append({"engine": "pipelined" if pipelined else "serial",
-                         "n_models": len(pool),
-                         "total_s": dt,
-                         "models_per_s": len(pool) / dt,
-                         "ratio": store.compression_ratio()})
-    return rows
-
-
 def main():
     rows = run()
     print(f"{'n_models':>9} {'avg_insert_s':>13} {'max_insert_s':>13}")
@@ -96,13 +72,7 @@ def main():
     for r in srows:
         print(f"{r['n_models']:9d} {r['avg_insert_s']:13.3f} {r['objects']:8d} "
               f"{r['ratio']:7.2f} {r['accounting_us']:11.2f}")
-    erows = run_commit_engines()
-    print(f"\n{'engine':>10} {'n_models':>9} {'total_s':>8} "
-          f"{'models/s':>9} {'ratio':>7}")
-    for r in erows:
-        print(f"{r['engine']:>10} {r['n_models']:9d} {r['total_s']:8.2f} "
-              f"{r['models_per_s']:9.2f} {r['ratio']:7.2f}")
-    return rows + srows + erows
+    return rows + srows
 
 
 if __name__ == "__main__":

@@ -41,29 +41,16 @@ def main() -> None:
     _csv("table4_compression", (time.perf_counter() - t0) * 1e6 / max(len(rows), 1),
          f"best_ratio={best['ratio']:.2f}@{best['graph']}")
     pipe = next(r for r in rows if r["technique"] == "pipeline")
-    _csv("pipeline", pipe["pip_commit_s"] * 1e6 / pipe["n_nodes"],
-         f"commit_x={pipe['commit_speedup']:.2f},"
-         f"checkout_x={pipe['checkout_speedup']:.2f},"
+    _csv("pipeline", pipe["commit_s"] * 1e6 / pipe["n_nodes"],
          f"models_per_s={pipe['commit_models_per_s']:.1f}")
     with open("BENCH_PR4.json", "w") as f:
         json.dump({
             "pool": {"n_nodes": pipe["n_nodes"], "d": pipe["d"]},
-            "commit": {
-                "serial_s": pipe["seq_commit_s"],
-                "pipelined_s": pipe["pip_commit_s"],
-                "speedup": pipe["commit_speedup"],
-                "models_per_s": pipe["commit_models_per_s"],
-            },
-            "checkout": {
-                "warm_serial_s": pipe["seq_warm_checkout_s"],
-                "warm_batched_s": pipe["pip_warm_checkout_s"],
-                "warm_speedup": pipe["checkout_speedup"],
-                "cold_serial_s": pipe["seq_cold_checkout_s"],
-                "cold_batched_s": pipe["pip_cold_checkout_s"],
-                "cold_speedup": pipe["cold_checkout_speedup"],
-            },
-            "dedup_ratio": {"serial": pipe["seq_ratio"],
-                            "pipelined": pipe["pip_ratio"]},
+            "commit": {"s": pipe["commit_s"],
+                       "models_per_s": pipe["commit_models_per_s"]},
+            "checkout": {"warm_s": pipe["warm_checkout_s"],
+                         "cold_s": pipe["cold_checkout_s"]},
+            "dedup_ratio": pipe["ratio"],
             "fold": {"depth5_chain_hops": pipe["fold_chain_hops"],
                      "dequants": 1},
         }, f, indent=1)

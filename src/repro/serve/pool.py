@@ -8,11 +8,11 @@ does. The pool:
 
 * loads the chain base of a manifest family exactly once (batched
   ``materialize_artifact`` checkout, PR 4) and pins it;
-* derives each served node's ``ResidentView`` by applying its folded
-  per-segment deltas directly over the resident base arrays — fused
-  ``ops.chain_apply`` on device backends, int32 segment sum + one host
-  dequant per segment on the ``ref`` backend (bit-identical, DESIGN.md
-  §10.2);
+* derives each served node's ``ResidentView`` by running the store's
+  segment-fold executor (``ArtifactStore.fold_hops``) over the resident
+  base arrays — fused ``ops.chain_apply`` on device backends, int32
+  segment sum + one host dequant per segment on the ``ref`` backend
+  (bit-identical, DESIGN.md §10.2);
 * aliases every parameter whose content hash matches a base parameter
   (the common case for sparse finetunes: unchanged tensors cost zero
   bytes per derivative);
@@ -22,26 +22,25 @@ does. The pool:
 * keeps an LRU over the derivative views' private (non-aliased) bytes, so
   N models stay resident in a fraction of N full copies.
 
-Chunked (``kind: chunked``) parameters and stores with folding disabled
-route through ``store.materialize_param`` — the chunk engine and the
-hopwise executor are the reconstruction truth there — and get the same
-bit-identity check.
+Chunked, ``xdelta`` and full parameters route through
+``store.materialize_param`` — the store's own engines are the
+reconstruction truth there — and get the same bit-identity check.
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from collections import OrderedDict
-from typing import Any, Dict, List, Optional, Tuple
+from collections import Counter, OrderedDict
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
 from repro.common.hashing import tensor_hash
 from repro.core.artifact import ModelArtifact
 from repro.core.graphir import LayerGraph
+from repro.kernels import ops
 from repro.obs import REGISTRY, span
-from repro.store.delta import decode_q, host_dequant
 
 
 class BitIdentityError(AssertionError):
@@ -237,11 +236,11 @@ class ModelPool:
                 self._count(params_aliased=1,
                             bytes_aliased=int(base_twin.nbytes))
                 continue
-            if entry["kind"] == "delta" and self.store.fold_enabled:
+            if entry["kind"] == "delta":
                 value = self._apply_chain(ref, key)
             else:
-                # chunked entries, full entries and hopwise-truth stores:
-                # the store's own executor IS the reconstruction truth
+                # chunked, xdelta and full entries: the store's own
+                # engines are the reconstruction truth
                 value = np.asarray(self.store.materialize_param(ref, key))
             if self.verify:
                 got = tensor_hash(value)
@@ -264,69 +263,23 @@ class ModelPool:
                             time.perf_counter() - t0)
 
     def _apply_chain(self, ref: str, key: str) -> np.ndarray:
-        """Derivative param = base value + folded per-segment deltas.
-
-        Same segmentation rule as the checkout executor (consecutive
-        float32 hops sharing one eps fold into one exact int32 sum and ONE
-        dequant, DESIGN.md §10.2), but executed over the pool's resident
-        base arrays instead of the tensor cache."""
+        """Derivative param = base value + folded per-segment deltas: the
+        store's fold executor, run over the pool's resident base arrays
+        instead of the tensor cache, on the pool's backend."""
         t_ref, t_key, t_entry, hops = self.store.chain_recipe(ref, key)
         value = self._base_by_hash.get(t_entry["hash"])
         if value is None:
             # chain bottoms out off the resident base (e.g. a chunked
             # terminal): materialize it through the store, cached there
             value = np.asarray(self.store.materialize_param(t_ref, t_key))
-        open_qs: List[np.ndarray] = []
-        open_eps = 0.0
-        for hop in hops:
-            q = decode_q(hop, self.store.cas.get_view(hop.blob))
-            self._count(chain_hops=1)
-            if hop.dtype == "float32":
-                if open_qs and hop.eps == open_eps:
-                    open_qs.append(q)
-                else:
-                    if open_qs:
-                        value = self._apply_segment(value, open_qs, open_eps)
-                    open_qs, open_eps = [q], hop.eps
-            else:
-                if open_qs:
-                    value = self._apply_segment(value, open_qs, open_eps)
-                    open_qs = []
-                value = self._dequant(value, q, hop.eps,
-                                      hop.dtype).reshape(hop.shape)
-        if open_qs:
-            value = self._apply_segment(value, open_qs, open_eps)
-        return np.asarray(value).reshape(hops[-1].shape) if hops \
-            else np.asarray(value)
-
-    def _kernel_backend(self) -> str:
-        from repro.kernels import ops
-        return self.backend or ops.default_backend()
-
-    def _dequant(self, value: np.ndarray, q: np.ndarray, eps: float,
-                 out_dtype: str) -> np.ndarray:
-        backend = self._kernel_backend()
-        if backend == "ref":
-            return host_dequant(value, q, eps, out_dtype=out_dtype)
-        from repro.kernels import ops
-        return np.asarray(ops.dequant_apply(np.asarray(value), q, eps=eps,
-                                            backend=backend,
-                                            out_dtype=out_dtype))
-
-    def _apply_segment(self, value: np.ndarray, qs: List[np.ndarray],
-                       eps: float) -> np.ndarray:
-        self._count(segments_applied=1)
-        backend = self._kernel_backend()
-        if backend != "ref" and len(qs) > 1:
-            from repro.kernels import ops
-            self._count(fused_applies=1)
-            return np.asarray(ops.chain_apply(
-                np.asarray(value), qs, eps=eps, backend=backend,
-                out_dtype="float32"))
-        acc = qs[0] if qs[0].dtype == np.int32 else qs[0].astype(np.int32)
-        for q in qs[1:]:
-            acc = np.add(acc, q.reshape(acc.shape), dtype=np.int32)
-        return self._dequant(value, acc, eps, "float32")
+        ran = Counter()
+        value, _ = self.store.fold_hops(
+            value, hops, ran, backend=self.backend or ops.default_backend(),
+            keep_state=False)
+        self._count(chain_hops=ran["chain_hops"],
+                    segments_applied=ran["segments_applied"],
+                    fused_applies=ran["fused_applies"])
+        return value
 
     # -- bookkeeping ---------------------------------------------------------
     def _count(self, **deltas: int) -> None:

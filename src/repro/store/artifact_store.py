@@ -28,14 +28,14 @@ paths are batched, pipelined engines (DESIGN.md §10):
 * ``materialize_artifact`` is the batched checkout: per-param chains resolve
   against shared manifest/fold state and decode+fold fans out across a
   thread pool (LZMA decode releases the GIL);
-* ``commit_artifact`` is a pipelined encoder by default: device quantization
+* ``commit_artifact`` is a pipelined encoder: device quantization
   (``ops.snapshot_fused``) overlaps host codec work on a thread pool, the
   parent's reconstruction state resolves once per chain, and all objects
   land through one buffered ``CAS.batch()`` with a single fsync at the
-  commit point. ``pipelined=False`` preserves the serial PR-1 path as the
-  benchmark baseline (it implies ``fold_enabled=False`` — the two paths
-  define reconstruction truth differently and must not be mixed in one
-  store, §10.2);
+  commit point. A commit stores what the fold executor (``fold_hops``)
+  rebuilds — one reconstruction truth, recorded as ``"truth": "fold"`` in
+  ``store_stats.json``; a repository that records any other is refused at
+  open (§10.2);
 * materialized tensors land in a byte-budget LRU (``cache_budget_bytes``)
   shared by every artifact the store serves; fold states (the open-segment
   ``(seg_base, Σq)`` pairs that let chains *extend* bit-exactly) land in a
@@ -49,16 +49,17 @@ import io
 import json
 import os
 import threading
-from collections import OrderedDict
+from collections import Counter, OrderedDict
 from concurrent import futures
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
 from repro.common.hashing import TensorHasher, bytes_hash, tensor_hash
 from repro.core.artifact import LazyParams, ModelArtifact, ParamRef
 from repro.core.graphir import LayerGraph
+from repro.kernels import ops
 from repro.obs import REGISTRY, propagate, span
 from repro.store import chunks as chunklib
 from repro.store.cas import CAS, DEFAULT_PACK_THRESHOLD
@@ -66,8 +67,7 @@ from repro.store.codecs import (bitpattern_apply, bitpattern_delta,
                                 bitpattern_delta_sparse, get_codec,
                                 pick_codec)
 from repro.store.delta import (CompressResult, ParamDelta, decode_q,
-                               decompress_param, delta_compression,
-                               host_dequant, host_snapshot,
+                               decompress_param, host_dequant, host_snapshot,
                                lcs_param_matching)
 from repro.store.manifest_walk import walk_manifests
 
@@ -237,6 +237,26 @@ class FoldCache:
 _XDELTA_CODEC = get_codec("sparse", preset=1)
 
 
+def _dequant(value: np.ndarray, q: np.ndarray, eps: float, out_dtype: str,
+             backend: str) -> np.ndarray:
+    """One dequant on ``backend``: the NumPy twin on ``ref`` (bit-identical
+    to the ref kernel, no dispatch), else ``ops.dequant_apply``."""
+    if backend == "ref":
+        return host_dequant(value, q, eps, out_dtype=out_dtype)
+    return np.asarray(ops.dequant_apply(np.asarray(value), q, eps=eps,
+                                        backend=backend, out_dtype=out_dtype))
+
+
+def _sum_q(qs: List[np.ndarray]) -> np.ndarray:
+    """Exact int32 sum of a segment's quantized deltas (narrowed int8 hops
+    widen on the first accumulation; a cached state's sum is never
+    mutated — the first add allocates)."""
+    acc = qs[0] if qs[0].dtype == np.int32 else qs[0].astype(np.int32)
+    for q in qs[1:]:
+        acc = np.add(acc, q.reshape(acc.shape), dtype=np.int32)
+    return acc
+
+
 class _ChunkPlan:
     """One leaf in the commit's chunk stream: its grid, the parent's entry
     and chunk chain when the grid is inherited, and what its consumed
@@ -287,11 +307,8 @@ class ArtifactStore:
                  delta_enabled: bool = True, per_param: bool = True,
                  max_chain_depth: int = 8,
                  cache_budget_bytes: int = 256 * 2**20,
-                 zero_frac_prefilter: float = 0.0,
                  backend: Optional[str] = None,
                  pack_threshold: int = DEFAULT_PACK_THRESHOLD,
-                 pipelined: bool = True,
-                 fold_enabled: bool = True,
                  fold_budget_bytes: int = 256 * 2**20,
                  lzma_preset: Optional[int] = None,
                  io_workers: Optional[int] = None,
@@ -323,17 +340,11 @@ class ArtifactStore:
         self.delta_enabled = delta_enabled
         self.per_param = per_param
         self.max_chain_depth = max_chain_depth
-        self.zero_frac_prefilter = zero_frac_prefilter
         self.backend = backend
-        self.pipelined = pipelined
-        # The serial baseline defines truth hop-by-hop; folding defines it
-        # segment-wise. One store must pick ONE definition (§10.2).
-        self.fold_enabled = fold_enabled and pipelined
-        # LZMA preset default: the pipelined engine ships with preset 0 —
-        # on quantized-delta streams it compresses as well as preset 1 at
-        # ~2x the encode/decode speed (see bench_compression's preset
-        # sweep); the serial baseline keeps the historical preset-1 codec.
-        if lzma_preset is None and pipelined and codec == "lzma":
+        # LZMA preset default 0: on quantized-delta streams it compresses
+        # as well as preset 1 at ~2x the encode/decode speed (see
+        # bench_compression's preset sweep)
+        if lzma_preset is None and codec == "lzma":
             lzma_preset = 0
         self.lzma_preset = lzma_preset
         self.io_workers = io_workers or max(2, min(4, os.cpu_count() or 2))
@@ -368,33 +379,14 @@ class ArtifactStore:
             with open(self._stats_path) as f:
                 payload = json.load(f)
             self.logical_bytes = payload.get("logical_bytes", 0)
-            self._adopt_truth(payload.get("truth"))
-
-    def _adopt_truth(self, recorded: Optional[str]) -> None:
-        """Enforce one reconstruction-truth definition per repository.
-
-        Fold and hop-by-hop reconstruction produce (equally valid but)
-        different bits for depth>=2 chains, so manifests written under one
-        definition must never be materialized under the other (§10.2). The
-        definition is persisted in store_stats.json at first commit:
-
-        * recorded == configured: fine;
-        * recorded missing but commits exist (store_stats.json predates the
-          marker — a PR-1..3 repo): its chains are hop-by-hop truth; adopt
-          that rather than silently diverge from the recorded hashes;
-        * recorded conflicts with an explicit config: fail fast."""
-        configured = "fold" if self.fold_enabled else "hopwise"
-        if recorded is None:
-            if self.fold_enabled:
-                self.fold_enabled = False
-                self.pipelined = False
-        elif recorded != configured:
-            raise ValueError(
-                f"store at {self.cas.root!r} was committed with "
-                f"{recorded!r} reconstruction truth but this instance is "
-                f"configured for {configured!r} — reopen with "
-                f"{'pipelined=True (default)' if recorded == 'fold' else 'pipelined=False'} "
-                f"(DESIGN.md §10.2: one truth definition per repository)")
+            if payload.get("truth") != "fold":
+                # fold and hop-by-hop reconstruction give different bits
+                # for depth>=2 chains: manifests whose hashes certify any
+                # other truth must not be read under this one (§10.2)
+                raise ValueError(
+                    f"store at {self.cas.root!r} records reconstruction "
+                    f"truth {payload.get('truth')!r} in store_stats.json; "
+                    f"this store reads only 'fold' (DESIGN.md §10.2)")
 
     def _kernel_backend(self) -> str:
         """``backend``, else what ``ops.default_backend()`` says: the
@@ -403,7 +395,6 @@ class ArtifactStore:
         Asked at each kernel use and never in ``__init__``: the hub, replica
         and GC processes build stores but run no kernel, and must not start
         a JAX backend (it would take the chip from a server on the host)."""
-        from repro.kernels import ops
         return self.backend or ops.default_backend()
 
     def _executor(self) -> ThreadPoolExecutor:
@@ -456,18 +447,7 @@ class ArtifactStore:
         commit_result: Optional[CompressResult] = None
         if self.delta_enabled and parent_ref is not None and artifact.params:
             if parent_manifest["depth"] < self.max_chain_depth:
-                if self.pipelined:
-                    result = self._delta_compress_pipelined(
-                        artifact, parent_ref, tests)
-                else:
-                    # serial baseline: lazy parent view, one param at a time
-                    parent = self.load_artifact(parent_ref)
-                    result = delta_compression(
-                        artifact, parent, t_thr=self.t_thr, eps=self.eps,
-                        codec=self.codec, tests=tests,
-                        per_param=self.per_param,
-                        zero_frac_prefilter=self.zero_frac_prefilter,
-                        backend=self._kernel_backend())
+                result = self._delta_compress(artifact, parent_ref, tests)
                 self.last_result = commit_result = result
                 if result.accepted:
                     deltas = result.deltas
@@ -487,7 +467,7 @@ class ArtifactStore:
             for key in artifact.params:
                 value = np.asarray(artifact.params[key])
                 # content identity for every entry (worker-precomputed for
-                # pipelined delta params)
+                # delta params)
                 thash = precomputed_hashes.get(key) or tensor_hash(value)
                 if key in deltas:
                     d = deltas[key]
@@ -539,8 +519,8 @@ class ArtifactStore:
             self.cas.flush()  # commit point: index + refcounts durable
         return ref
 
-    def _delta_compress_pipelined(self, child: ModelArtifact, parent_ref: str,
-                                  tests: Sequence = ()) -> CompressResult:
+    def _delta_compress(self, child: ModelArtifact, parent_ref: str,
+                        tests: Sequence = ()) -> CompressResult:
         """Throughput-first Algorithm 1 (DESIGN.md §10.1).
 
         Stages, overlapped across a thread pool (GIL-releasing LZMA/XLA):
@@ -551,11 +531,11 @@ class ArtifactStore:
            (``ops.snapshot_fused``, fingerprint elided: commit never reads
            it), encodes the quantized delta, and derives the child's stored
            truth with one fold-extended dequant;
-        3. acceptance and test-gating mirror :func:`delta_compression`
-           exactly (per-param or whole-model, ``t_thr`` rejection).
+        3. acceptance: per param when ``per_param`` (a delta must be
+           smaller than its tensor), else the paper's whole-model rule;
+           then the registered tests, rejecting all when any score moves
+           by more than ``t_thr``.
         """
-        from repro.kernels import ops
-
         cod = self._codec_obj
         parent_lazy = self.load_artifact(parent_ref)
         pairs = [(pk, ck) for pk, ck in lcs_param_matching(parent_lazy, child)]
@@ -577,14 +557,12 @@ class ArtifactStore:
                 return None
             with span("commit.quantize", cat="store", key=ckey):
                 if host:  # numpy twin, bit-identical, no dispatch overhead
-                    q, nz, _narrow = host_snapshot(p1, p2, self.eps)
+                    q, _nz, _narrow = host_snapshot(p1, p2, self.eps)
                 else:
-                    q, nz, _fp, _narrow = ops.snapshot_fused(
+                    q, _nz, _fp, _narrow = ops.snapshot_fused(
                         p1, p2, eps=self.eps, backend=backend,
                         with_fingerprint=False)
                     q = np.asarray(q)
-            if nz / q.size < self.zero_frac_prefilter:
-                return None  # on-device pre-filter: won't compress
             with span("commit.encode", cat="store", key=ckey):
                 blob = cod.encode(q)
             if self.per_param and len(blob) >= p2.nbytes:
@@ -667,34 +645,24 @@ class ArtifactStore:
         if eps is None:
             eps = self.eps
         backend = self._kernel_backend()
-        if backend == "ref":
-            dequant = host_dequant
+        if dtype != "float32":
+            return _dequant(parent_value, q32, eps, dtype, backend), None
+        fs = self.fold_cache.get((parent_ref, parent_key))
+        if fs is None:
+            e = self._entry(parent_ref, parent_key)
+            if e["kind"] == "delta":  # state evicted: recompute it
+                _, fs = self._materialize_with_state(parent_ref, parent_key)
+        if fs is not None and fs.eps == eps:
+            state = FoldState(
+                seg_base=fs.seg_base,
+                q_open=np.add(fs.q_open, q32.reshape(fs.q_open.shape),
+                              dtype=np.int32),
+                eps=eps)
         else:
-            from repro.kernels import ops
-
-            def dequant(v, q, e_, out_dtype="float32"):
-                return np.asarray(ops.dequant_apply(
-                    np.asarray(v), q, eps=e_, backend=backend,
-                    out_dtype=out_dtype))
-
-        if dtype == "float32" and self.fold_enabled:
-            fs = self.fold_cache.get((parent_ref, parent_key))
-            if fs is None:
-                e = self._entry(parent_ref, parent_key)
-                if e["kind"] == "delta":  # state evicted: recompute it
-                    _, fs = self._materialize_with_state(parent_ref,
-                                                         parent_key)
-            if fs is not None and fs.eps == eps:
-                state = FoldState(
-                    seg_base=fs.seg_base,
-                    q_open=np.add(fs.q_open, q32.reshape(fs.q_open.shape),
-                                  dtype=np.int32),
-                    eps=eps)
-            else:
-                state = FoldState(seg_base=np.asarray(parent_value),
-                                  q_open=q32, eps=eps)
-            return dequant(state.seg_base, state.q_open, eps), state
-        return dequant(parent_value, q32, eps, out_dtype=dtype), None
+            state = FoldState(seg_base=np.asarray(parent_value),
+                              q_open=q32, eps=eps)
+        return (_dequant(state.seg_base, state.q_open, eps, "float32",
+                         backend), state)
 
     # -- step-delta commit engine (DESIGN.md §15) --------------------------------
     def _full_step_entry(self, key: str, value: np.ndarray,
@@ -1004,7 +972,6 @@ class ArtifactStore:
                     value):
                 host = value
             else:
-                from repro.kernels import ops
                 host, view = ops.host_copy(value)
             if sp is not None:
                 sp.args["view"] = view
@@ -1516,8 +1483,8 @@ class ArtifactStore:
 
     def chain_recipe(self, ref: str, key: str
                      ) -> Tuple[str, str, Dict[str, Any], List[DeltaHop]]:
-        """Structural chain walk for out-of-store executors (the serving
-        pool's derivative-view materialization, DESIGN.md §13).
+        """Structural chain walk for the serving pool, which runs
+        :meth:`fold_hops` over its resident base (DESIGN.md §13).
 
         Returns ``(terminal_ref, terminal_key, terminal_entry, hops)``:
         the chain base entry (``full`` or ``chunked``) plus every delta hop
@@ -1573,71 +1540,16 @@ class ArtifactStore:
                         return ("value", v), pending
                 return ("tensor", e["tensor"]), pending
             if pending:
-                if self.fold_enabled:
-                    fs = self.fold_cache.get((cur_ref, cur_key))
-                    if fs is not None:
-                        with self._lock:
-                            self.io_stats["fold_hits"] += 1
-                        return ("fold", fs), pending
+                fs = self.fold_cache.get((cur_ref, cur_key))
+                if fs is not None:
+                    with self._lock:
+                        self.io_stats["fold_hits"] += 1
+                    return ("fold", fs), pending
                 if self._is_segment_boundary(pending[-1], e):
                     v = self.cache.get((cur_ref, cur_key))
                     if v is not None:
                         return ("value", v), pending
             pending.append(self._hop_of(e, cur_ref, cur_key))
-
-    def _dequant(self, value: np.ndarray, q: np.ndarray, eps: float,
-                 out_dtype: str) -> np.ndarray:
-        """One counted dequant application.
-
-        The pipelined engine uses the numpy host path on CPU hosts (bit-
-        identical to the jax ref kernel, no dispatch overhead); the serial
-        baseline (``pipelined=False``) keeps the original per-hop jax
-        dispatch so benchmarks measure the pre-pipeline engine faithfully.
-        Device backends always dispatch."""
-        backend = self._kernel_backend()
-        if self.pipelined and backend == "ref":
-            out = host_dequant(value, q, eps, out_dtype=out_dtype)
-        else:
-            from repro.kernels import ops
-            out = np.asarray(ops.dequant_apply(
-                np.asarray(value), q, eps=eps, backend=backend,
-                out_dtype=out_dtype))
-        with self._lock:
-            self.io_stats["dequant_calls"] += 1
-        self._count_materialization(out)
-        return out
-
-    def _sum_q(self, qs: List[np.ndarray]) -> np.ndarray:
-        """Exact int32 sum of a segment's quantized deltas (narrowed int8
-        hops widen on the first accumulation; a cached state's sum is
-        never mutated — the first add allocates)."""
-        acc = qs[0] if qs[0].dtype == np.int32 else qs[0].astype(np.int32)
-        for q in qs[1:]:
-            acc = np.add(acc, q.reshape(acc.shape), dtype=np.int32)
-        return acc
-
-    def _apply_segment(self, value: np.ndarray, open_qs: List[np.ndarray],
-                       eps: float, need_sum: bool
-                       ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
-        """Close one fold segment: value <- dequant(value, Σ open_qs, eps).
-
-        On device backends a multi-hop segment goes through the fused
-        Pallas chain-apply kernel (one HBM pass over base + q stack, int32
-        reduction in VMEM) — bit-identical to host sum + dequant. Returns
-        ``(value, qsum)``; the sum is only computed when the caller needs
-        it for a FoldState (``need_sum``) or the host path uses it."""
-        backend = self._kernel_backend()
-        if len(open_qs) > 1 and backend != "ref":
-            from repro.kernels import ops
-            out = np.asarray(ops.chain_apply(
-                np.asarray(value), open_qs, eps=eps, backend=backend,
-                out_dtype="float32"))
-            with self._lock:
-                self.io_stats["dequant_calls"] += 1
-            self._count_materialization(out)
-            return out, (self._sum_q(open_qs) if need_sum else None)
-        qsum = self._sum_q(open_qs)
-        return self._dequant(value, qsum, eps, "float32"), qsum
 
     def _materialize_with_state(self, ref: str, key: str,
                                 plan: Optional[ReconstructionPlan] = None
@@ -1649,51 +1561,97 @@ class ArtifactStore:
         A full-base ``plan`` (from ``resolve_chain``) substitutes for the
         walk; cache-base plans are not segment-aware and are re-resolved."""
         if plan is not None and plan.base_kind == "full":
-            origin, pending = ("tensor", plan.base), list(reversed(plan.hops))
+            origin, hops = ("tensor", plan.base), list(plan.hops)
         else:
             origin, pending = self._resolve_recipe(ref, key)
-        hops = list(reversed(pending))  # base -> tip order
-        kind, payload = origin
-        open_qs: List[np.ndarray] = []
-        open_eps = 0.0
+            hops = pending[::-1]  # base -> tip order
+        kind, start = origin
         if kind == "tensor":
-            value = self.cas.get_tensor(payload)
-            self._count_materialization(value)
-        elif kind == "value":
-            value = payload
-        else:  # fold state: its accumulated sum seeds the open segment
-            fs: FoldState = payload
-            value, open_qs, open_eps = fs.seg_base, [fs.q_open], fs.eps
+            start = self.cas.get_tensor(start)
+            self._count_materialization(start)
+        ran = Counter()
+        value, state = self.fold_hops(start, hops, ran)
+        with self._lock:
+            for k in ("chain_hops", "hops_folded", "dequant_calls"):
+                self.io_stats[k] += ran[k]
+            self.io_stats["tensors_materialized"] += ran["dequant_calls"]
+            self.io_stats["bytes_materialized"] += ran["bytes_out"]
+        return value, state
+
+    def fold_hops(self, origin: Union[np.ndarray, FoldState],
+                  hops: Sequence[DeltaHop], ran: Counter,
+                  backend: Optional[str] = None, keep_state: bool = True
+                  ) -> Tuple[np.ndarray, Optional[FoldState]]:
+        """The segment-fold executor (DESIGN.md §10.2): apply ``hops``, in
+        base->tip order, to ``origin`` — a value, or a cached FoldState
+        whose open segment the first hops may extend.
+
+        Consecutive float32 hops sharing one eps fold into one exact int32
+        sum and ONE dequant; on a device backend a segment of more than one
+        hop is one fused ``ops.chain_apply`` (one HBM pass over base + q
+        stack, int32 reduction in VMEM, bit-identical to host sum +
+        dequant). Any other hop is one dequant to its own dtype. The
+        ``ref`` backend runs the NumPy twins, bit-identical to the ref
+        kernels with no dispatch overhead.
+
+        Returns the tip value and, with ``keep_state``, the FoldState of
+        its still-open last segment (None when the last hop was not
+        float32). Counts what it ran into ``ran``: ``chain_hops`` (hops
+        decoded), ``hops_folded`` (hops that extended an open segment),
+        ``segments_applied`` (float32 segments closed), ``fused_applies``
+        (those closed by one ``chain_apply``), ``dequant_calls``
+        (dequant and chain_apply calls) and ``bytes_out`` (the bytes
+        those calls produced). ``backend`` defaults to the store's."""
+        backend = backend or self._kernel_backend()
+
+        def counted(out):
+            out = np.asarray(out)
+            ran["dequant_calls"] += 1
+            ran["bytes_out"] += int(out.nbytes)
+            return out
+
+        def close(v, qs, eps):
+            ran["segments_applied"] += 1
+            if len(qs) > 1 and backend != "ref":
+                ran["fused_applies"] += 1
+                return counted(ops.chain_apply(
+                    np.asarray(v), qs, eps=eps, backend=backend,
+                    out_dtype="float32")), None
+            qsum = _sum_q(qs)
+            return counted(_dequant(v, qsum, eps, "float32", backend)), qsum
+
+        if isinstance(origin, FoldState):  # its sum seeds the open segment
+            value, open_qs, open_eps = (origin.seg_base, [origin.q_open],
+                                        origin.eps)
+        else:
+            value, open_qs, open_eps = origin, [], 0.0
         for hop in hops:
             q = decode_q(hop, self.cas.get_view(hop.blob))
-            with self._lock:
-                self.io_stats["chain_hops"] += 1
-            if self.fold_enabled and hop.dtype == "float32":
-                if open_qs and hop.eps == open_eps:
-                    open_qs.append(q)
-                    with self._lock:
-                        self.io_stats["hops_folded"] += 1
-                else:
-                    if open_qs:
-                        value, _ = self._apply_segment(value, open_qs,
-                                                       open_eps, False)
-                    open_qs, open_eps = [q], hop.eps
+            ran["chain_hops"] += 1
+            if hop.dtype == "float32" and open_qs and hop.eps == open_eps:
+                open_qs.append(q)
+                ran["hops_folded"] += 1
+                continue
+            if open_qs:
+                value, _ = close(value, open_qs, open_eps)
+                open_qs = []
+            if hop.dtype == "float32":
+                open_qs, open_eps = [q], hop.eps
             else:
-                if open_qs:
-                    value, _ = self._apply_segment(value, open_qs, open_eps,
-                                                   False)
-                    open_qs = []
-                value = self._dequant(value, q, hop.eps, hop.dtype
-                                      ).reshape(hop.shape)
+                value = counted(_dequant(value, q, hop.eps, hop.dtype,
+                                         backend)).reshape(hop.shape)
         state = None
         if open_qs:
-            value = np.asarray(value)
-            new_value, qsum = self._apply_segment(value, open_qs, open_eps,
-                                                  True)
-            state = FoldState(seg_base=value, q_open=qsum, eps=open_eps)
-            value = new_value
+            seg_base = np.asarray(value)
+            value, qsum = close(seg_base, open_qs, open_eps)
+            if keep_state:
+                state = FoldState(seg_base=seg_base,
+                                  q_open=(_sum_q(open_qs) if qsum is None
+                                          else qsum),
+                                  eps=open_eps)
+        value = np.asarray(value)
         if hops:
-            value = np.asarray(value).reshape(hops[-1].shape)
+            value = value.reshape(hops[-1].shape)
         return value, state
 
     def _materialize_xdelta(self, ref: str, key: str,
@@ -1846,11 +1804,13 @@ class ArtifactStore:
 
         Recursively materializes every FULL ancestor artifact to resolve the
         chain — O(full model x chain depth) peak memory. Kept as the
-        benchmark baseline for ``benchmarks/bench_compression.py``; all
-        production paths go through ``load_artifact``/``materialize_param``.
-        Reconstruction follows the same segment-folding semantics (§10.2) —
-        the recursion threads each param's open-segment state — so its
-        output is bit-identical to the plan engine's."""
+        independent reference the tests and ``bench_compression`` compare
+        the fold executor (:meth:`fold_hops`) against: it shares none of
+        its code. All production paths go through
+        ``load_artifact``/``materialize_param``. Reconstruction follows the
+        same segment-folding semantics (§10.2) — the recursion threads each
+        param's open-segment state — so its output is bit-identical to the
+        plan engine's."""
         artifact, _ = self._load_recursive_with_states(ref)
         return artifact
 
@@ -1881,7 +1841,7 @@ class ArtifactStore:
             hop = self._hop_of(e, ref, key)
             q = decode_q(hop, self.cas.get_view(hop.blob))
             ps = parent_states.get(pkey)
-            if self.fold_enabled and hop.dtype == "float32":
+            if hop.dtype == "float32":
                 if ps is not None and ps.eps == hop.eps:
                     st = FoldState(seg_base=ps.seg_base,
                                    q_open=np.add(ps.q_open, q.reshape(
@@ -2113,8 +2073,7 @@ class ArtifactStore:
             tmp = self._stats_path + ".tmp"
             with open(tmp, "w") as f:
                 json.dump({"logical_bytes": self.logical_bytes,
-                           "truth": ("fold" if self.fold_enabled
-                                     else "hopwise")}, f)
+                           "truth": "fold"}, f)
             os.replace(tmp, self._stats_path)
 
     # -- accounting -------------------------------------------------------------------

@@ -1,9 +1,10 @@
-"""Delta compression between related models (paper §4, Algorithm 1).
+"""Delta compression between related models (paper §4, Algorithm 1): the
+pieces the commit engine (``ArtifactStore._delta_compress``) runs on.
 
 Parent and child need not share an architecture: an LCS over the two models'
 parameter sequences (in layer-graph topological order, items equal iff
 shape+dtype match) yields the parameter mapping; matched pairs are quantized
-(`repro.kernels.ops.delta_quantize`, the Pallas-accelerated hot path) and
+(``ops.snapshot_fused`` or its NumPy twin :func:`host_snapshot`) and
 losslessly compressed. Compression is *accepted* only if it actually saves
 bytes AND, when tests are registered, the reconstructed model's scores stay
 within ``t_thr`` of the original — otherwise the uncompressed tensor is kept.
@@ -12,7 +13,7 @@ within ``t_thr`` of the original — otherwise the uncompressed tensor is kept.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -108,10 +109,6 @@ class ParamDelta:
     raw_bytes: int          # uncompressed child tensor size
     qdtype: str = "int32"   # int8 when the fused kernel narrowed (§Perf-C)
 
-    @property
-    def saving(self) -> float:
-        return self.raw_bytes / max(len(self.blob), 1)
-
 
 @dataclasses.dataclass
 class CompressResult:
@@ -132,74 +129,6 @@ class CompressResult:
     @property
     def ratio(self) -> float:
         return self.raw_bytes / max(self.compressed_bytes, 1)
-
-
-def delta_compression(m2: ModelArtifact, m1: ModelArtifact,
-                      t_thr: float = 0.5, eps: float = 1e-4,
-                      codec: str = "lzma", tests: Sequence = (),
-                      per_param: bool = True,
-                      zero_frac_prefilter: float = 0.0,
-                      backend: Optional[str] = None) -> CompressResult:
-    """Paper Algorithm 1 — compress m1 - m2 (m1 parent, m2 child).
-
-    ``per_param=True`` accepts/rejects each tensor independently (beyond-paper
-    refinement); ``False`` reproduces the paper's whole-model accept/reject.
-    ``zero_frac_prefilter``: skip host compression when the on-device zero
-    fraction predicts a ratio <= 1 (DESIGN.md §3 pre-filter).
-    """
-    cod = get_codec(codec)
-    pairs = lcs_param_matching(m1, m2)
-    candidates: Dict[str, ParamDelta] = {}
-    recon_params: Dict[str, np.ndarray] = {}
-
-    for pkey, ckey in pairs:
-        p1 = np.asarray(m1.params[pkey])
-        p2 = np.asarray(m2.params[ckey])
-        if p1.size == 0:
-            continue
-        # fused snapshot pass: quantized delta (int8-narrowed when it fits)
-        # + zero stats + fingerprint, one HBM read of each input (§Perf-C)
-        q, nz, _fp, _narrow = ops.snapshot_fused(p1, p2, eps=eps,
-                                                 backend=backend)
-        q = np.asarray(q)
-        zero_frac = nz / q.size
-        if zero_frac < zero_frac_prefilter:
-            continue  # on-device pre-filter says "won't compress" — skip host work
-        blob = cod.encode(q)
-        delta = ParamDelta(child_key=ckey, parent_key=pkey, blob=blob,
-                           codec=codec, eps=eps, shape=tuple(p2.shape),
-                           dtype=str(p2.dtype), raw_bytes=int(p2.nbytes),
-                           qdtype=str(q.dtype))
-        if per_param and len(blob) >= p2.nbytes:
-            continue  # no saving for this tensor
-        candidates[ckey] = delta
-        recon = np.asarray(ops.dequant_apply(p1, q, eps=eps, backend=backend,
-                                             out_dtype=p2.dtype))
-        recon_params[ckey] = recon.reshape(p2.shape)
-
-    total_raw = m2.nbytes()
-    delta_raw = sum(d.raw_bytes for d in candidates.values())
-    delta_compressed = sum(len(d.blob) for d in candidates.values())
-    storage_saving = delta_raw / max(delta_compressed, 1)
-
-    if not candidates or (not per_param and storage_saving < 1.0):
-        return CompressResult(False, {}, m2, {}, total_raw, total_raw)
-
-    # m2' = m2 with the compressed params replaced by their reconstructions
-    m2_prime = m2.replace_params(recon_params)
-
-    test_deltas: Dict[str, float] = {}
-    for t in tests:
-        before = float(t.fn(m2))
-        after = float(t.fn(m2_prime))
-        test_deltas[t.name] = after - before
-        if abs(after - before) > t_thr:
-            # accuracy drop beyond threshold — reject compression entirely
-            return CompressResult(False, {}, m2, test_deltas, total_raw, total_raw)
-
-    compressed_total = (total_raw - delta_raw) + delta_compressed
-    return CompressResult(True, candidates, m2_prime, test_deltas,
-                          total_raw, compressed_total)
 
 
 def host_snapshot(p1: np.ndarray, p2: np.ndarray, eps: float

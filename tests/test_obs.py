@@ -5,6 +5,7 @@ import contextlib
 import json
 import os
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -556,6 +557,20 @@ def _get_json(url):
         return json.loads(r.read())
 
 
+def _scrape_until(url, key, want, deadline_s=5.0):
+    """``/api/metrics`` once sample ``key`` reads ``want`` (or at the
+    deadline): a handler observes its request's latency after it has sent
+    the response, so a client can hold its last reply before the server
+    has counted that request."""
+    end = time.monotonic() + deadline_s
+    while True:
+        ctype, text = _get_text(url + "/api/metrics")
+        samples, types = _parse_prometheus(text)
+        if samples.get(key) == want or time.monotonic() > end:
+            return ctype, samples, types
+        time.sleep(0.01)
+
+
 def _post_json(url, obj):
     req = urllib.request.Request(
         url, data=json.dumps(obj).encode(), method="POST",
@@ -609,14 +624,13 @@ def test_serve_api_metrics_and_latency(tmp_path):
     try:
         for _ in range(3):
             _post_json(server.url + "/api/predict/prod", {})
-        ctype, text = _get_text(server.url + "/api/metrics")
-        assert ctype.startswith("text/plain")
-        samples, types = _parse_prometheus(text)
         inst = app.counters.instance
-        assert samples[f'mgit_serve_predictions{{instance="{inst}"}}'] == 3
         key = ('mgit_http_request_seconds_count{instance="%s",'
                'method="POST",route="/api/predict/:endpoint",'
                'service="serve"}' % inst)
+        ctype, samples, types = _scrape_until(server.url, key, 3)
+        assert ctype.startswith("text/plain")
+        assert samples[f'mgit_serve_predictions{{instance="{inst}"}}'] == 3
         assert samples[key] == 3
         lat = _get_json(server.url + "/api/stats")["request_latency"]
         assert lat["POST /api/predict/:endpoint"]["count"] == 3
@@ -633,13 +647,12 @@ def test_unknown_paths_collapse_to_other_route_label(tmp_path):
         for i in range(3):  # distinct junk paths -> ONE label value
             with pytest.raises(urllib.error.HTTPError):
                 _get_json(server.url + f"/api/junk{i}")
-        samples, _ = _parse_prometheus(
-            _get_text(server.url + "/api/metrics")[1])
-        junk = [k for k in samples if "junk" in k]
-        assert not junk  # cardinality stays bounded
         inst = app.stats.instance
         key = ('mgit_http_request_seconds_count{instance="%s",'
                'method="GET",route="other",service="hub"}' % inst)
+        _, samples, _ = _scrape_until(server.url, key, 3)
+        junk = [k for k in samples if "junk" in k]
+        assert not junk  # cardinality stays bounded
         assert samples[key] == 3
     finally:
         server.shutdown()

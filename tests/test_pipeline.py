@@ -153,35 +153,120 @@ def test_reopened_store_reproduces_committed_hashes(tmp_path):
         assert tensor_hash(np.asarray(value)) == e["hash"], key
 
 
+# A seeded depth-4 chain with pinned bits. The float32 leaves take two
+# same-eps segments (1e-4, 1e-4 | 1e-3, 1e-3); the bf16 leaf takes one
+# quantized hop per commit, none of which folds. Any change to how a
+# chain is committed or rebuilt moves the tip's ref or a hash below.
+GOLDEN_LEAVES = {"L0/w": ((24, 24), "float32"), "L0/b": ((24,), "float32"),
+                 "L1/w": ((24, 24), "bfloat16"),
+                 "head/w": ((24, 4), "float32")}
+GOLDEN_TIP = ("m_79e5418660dcd11a7cd8c8ab24aef98186e035fe714b42b62cd"
+              "05812f997022c")
+GOLDEN_HASHES = {
+    "L0/b": "013152f54d879c975ca407cdca8fbd4f890e1ad31217c50d36f857982300851b",
+    "L0/w": "cb1bd2e4cdb244f3d4c4171b71bbb6e4d5214cb92702461f3d3da2a6d3420026",
+    "L1/w": "1facccbba21974241996a5e50c24177a91131f5fe4fdfb3f5437f3f165f5166d",
+    "head/w": "8b0986d601cd083e0f15b9f53edea0ed45590313aed1b77334281c0095"
+              "8bd1cd",
+}
+
+
+def _golden_chain(root):
+    import ml_dtypes  # noqa: F401  (registers bfloat16 with NumPy)
+    from repro.core import LayerGraph, LayerNode, ModelArtifact
+    rng = np.random.default_rng(1234)
+    graph = LayerGraph.chain([
+        LayerNode("L0", "linear", params={"w": GOLDEN_LEAVES["L0/w"],
+                                          "b": GOLDEN_LEAVES["L0/b"]}),
+        LayerNode("L1", "linear", params={"w": GOLDEN_LEAVES["L1/w"]}),
+        LayerNode("head", "linear", params={"w": GOLDEN_LEAVES["head/w"]})])
+    model = ModelArtifact(graph, {
+        k: rng.normal(size=s).astype(np.dtype(dt))
+        for k, (s, dt) in GOLDEN_LEAVES.items()}, model_type="toy")
+    scale = {"float32": 1e-3, "bfloat16": 3e-2}
+    store = ArtifactStore(root=root, backend="ref", eps=1e-4)
+    ref = store.commit_artifact("v0", model)
+    for v, eps in enumerate((1e-4, 1e-4, 1e-3, 1e-3), start=1):
+        store.eps = eps
+        model = model.map_params(lambda k, x: (
+            x.astype(np.float32)
+            + rng.normal(scale=scale[str(x.dtype)], size=x.shape)
+        ).astype(x.dtype))
+        ref = store.commit_artifact(f"v{v}", model, parent_ref=ref)
+    return store, ref
+
+
+def test_golden_chain_pins_tip_ref_and_hashes(tmp_path):
+    from repro.common.hashing import tensor_hash
+    from repro.serve import ModelPool
+    store, tip = _golden_chain(str(tmp_path))
+    assert tip == GOLDEN_TIP
+    manifest = store.get_manifest(tip)
+    assert manifest["depth"] == 4
+    assert {k: e["hash"] for k, e in manifest["params"].items()} \
+        == GOLDEN_HASHES
+    for key in GOLDEN_HASHES:
+        assert len(store.chain_recipe(tip, key)[3]) == 4, key
+
+    # a cold store rebuilds the same bits: two dequants per float32 leaf
+    # (one per segment), one per bf16 hop
+    fresh = ArtifactStore(root=str(tmp_path), backend="ref")
+    fresh.reset_io_stats()
+    got = fresh.materialize_artifact(tip)
+    assert {k: tensor_hash(np.asarray(v)) for k, v in got.params.items()} \
+        == GOLDEN_HASHES
+    io = fresh.io_stats
+    assert io["chain_hops"] == 16
+    assert io["hops_folded"] == 6
+    assert io["dequant_calls"] == 3 * 2 + 4
+
+    # the serving pool rebuilds them over its resident base and checks
+    # every one against the manifest (BitIdentityError otherwise); on a
+    # device backend each two-hop segment is one fused chain_apply
+    for backend, fused in (("ref", 0), ("interpret", 6)):
+        pool = ModelPool(fresh, backend=backend)
+        pool.get(tip)
+        s = pool.stats()
+        assert s["params_verified"] == 4
+        assert s["chain_hops"] == 16
+        assert s["segments_applied"] == 6
+        assert s["fused_applies"] == fused
+
+
+def _rewrite_truth_marker(root, truth):
+    """Set (or, with ``truth=None``, drop) store_stats.json's marker."""
+    stats_path = os.path.join(root, "store_stats.json")
+    with open(stats_path) as f:
+        payload = json.load(f)
+    payload.pop("truth")
+    if truth is not None:
+        payload["truth"] = truth
+    with open(stats_path, "w") as f:
+        json.dump(payload, f)
+
+
 def test_truth_marker_rejects_mismatched_reopen(tmp_path):
-    """One reconstruction-truth definition per repository (§10.2): a repo
-    committed under fold truth must refuse a hop-by-hop reopen (and vice
-    versa) instead of silently materializing different bits than its
-    manifest hashes."""
+    """One reconstruction truth (§10.2): a repository whose store_stats.json
+    records another truth (older repositories wrote the hop-by-hop one)
+    is refused at open, instead of materializing bits its manifest hashes
+    do not certify."""
     store = ArtifactStore(root=str(tmp_path))
     _build_chain(store, 2)
-    with pytest.raises(ValueError, match="reconstruction truth"):
-        ArtifactStore(root=str(tmp_path), pipelined=False)
+    _rewrite_truth_marker(str(tmp_path), "hop-by-hop")
+    with pytest.raises(ValueError, match="reconstruction truth") as err:
+        ArtifactStore(root=str(tmp_path))
+    assert str(tmp_path) in str(err.value)
 
 
-def test_legacy_repo_without_marker_adopts_hopwise(tmp_path):
-    """A store_stats.json predating the truth marker (PR-1..3 repo) means
-    hop-by-hop chains: reopening with the fold default must adopt hopwise
-    so materialized bits keep matching the recorded manifest hashes."""
-    from repro.common.hashing import tensor_hash
-    store = ArtifactStore(root=str(tmp_path), pipelined=False)
-    refs, _ = _build_chain(store, 3)
-    stats_path = os.path.join(str(tmp_path), "store_stats.json")
-    payload = json.load(open(stats_path))
-    del payload["truth"]  # simulate the pre-§10 file format
-    json.dump(payload, open(stats_path, "w"))
-
-    reopened = ArtifactStore(root=str(tmp_path))  # fold default
-    assert not reopened.fold_enabled
-    manifest = reopened.get_manifest(refs[-1])
-    for key, e in manifest["params"].items():
-        value = reopened.materialize_param(refs[-1], key)
-        assert tensor_hash(np.asarray(value)) == e["hash"], key
+def test_repo_without_truth_marker_is_refused(tmp_path):
+    """A store_stats.json with no truth marker predates it: its chains may
+    certify hop-by-hop bits, so the open fails rather than guessing."""
+    store = ArtifactStore(root=str(tmp_path))
+    _build_chain(store, 2)
+    _rewrite_truth_marker(str(tmp_path), None)
+    with pytest.raises(ValueError, match="reconstruction truth") as err:
+        ArtifactStore(root=str(tmp_path))
+    assert str(tmp_path) in str(err.value)
 
 
 def test_pipelined_commit_respects_accuracy_gate(tmp_path):

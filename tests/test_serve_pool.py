@@ -73,7 +73,7 @@ def test_pool_folded_chain_matches_store(tmp_path):
     for k in truth.params:
         np.testing.assert_array_equal(np.asarray(view.params[k]),
                                       np.asarray(truth.params[k]), err_msg=k)
-    if store.get_manifest(ref)["depth"] == 3 and store.fold_enabled:
+    if store.get_manifest(ref)["depth"] == 3:
         s = pool.stats()
         assert s["chain_hops"] >= 3
         assert s["segments_applied"] >= 1

@@ -6,8 +6,7 @@ import pytest
 from hyp_compat import given, settings, st
 
 from repro.core import LineageGraph
-from repro.store import (CAS, CODECS, ArtifactStore, delta_compression,
-                         lcs_param_matching)
+from repro.store import CAS, CODECS, ArtifactStore, lcs_param_matching
 from repro.core.lineage import RegisteredTest
 
 from helpers import finetune_like, l2_test, make_chain_model, prune_like
@@ -113,6 +112,15 @@ def test_lcs_differing_architectures():
 # delta compression (Algorithm 1)
 # ---------------------------------------------------------------------------
 
+def _commit_pair(parent, child, tests=(), **store_kw):
+    """Commit ``child`` onto ``parent`` (in memory unless ``root`` is
+    given); return the commit engine's Algorithm 1 result."""
+    store = ArtifactStore(**store_kw)
+    parent_ref = store.commit_artifact("parent", parent)
+    store.commit_artifact("child", child, parent_ref=parent_ref, tests=tests)
+    return store.last_result
+
+
 @given(scale=st.floats(1e-6, 1e-4), density=st.floats(0.0, 0.5),
        seed=st.integers(0, 100))
 @settings(max_examples=15, deadline=None)
@@ -120,7 +128,7 @@ def test_delta_error_bound_property(scale, density, seed):
     """Reconstruction error is bounded by the quantization step (~eps)."""
     parent = make_chain_model(seed=0)
     child = finetune_like(parent, seed=seed, scale=scale, density=density)
-    res = delta_compression(child, parent, eps=1e-4, codec="zlib")
+    res = _commit_pair(parent, child, eps=1e-4, codec="zlib")
     for k in child.params:
         err = np.max(np.abs(res.reconstructed.params[k] - child.params[k]))
         assert err <= 2 * np.log1p(1e-4)  # one quantization step
@@ -129,7 +137,7 @@ def test_delta_error_bound_property(scale, density, seed):
 def test_delta_rejected_for_unrelated():
     parent = make_chain_model(seed=0)
     child = make_chain_model(seed=99)  # totally different values
-    res = delta_compression(child, parent, codec="lzma", per_param=True)
+    res = _commit_pair(parent, child, codec="lzma", per_param=True)
     # dense large deltas shouldn't beat raw storage meaningfully
     assert res.ratio < 2.0
 
@@ -138,15 +146,15 @@ def test_delta_accuracy_gate():
     parent = make_chain_model(seed=0)
     child = finetune_like(parent, seed=1)
     tests = [RegisteredTest(name="l2", fn=l2_test, model_type="toy")]
-    res = delta_compression(child, parent, t_thr=0.0, eps=0.5,  # huge eps
-                            codec="lzma", tests=tests)
+    res = _commit_pair(parent, child, tests=tests, t_thr=0.0,
+                       eps=0.5, codec="lzma")  # huge eps
     assert not res.accepted  # big eps wrecks the test score -> rejected
 
 
 def test_delta_whole_model_mode():
     parent = make_chain_model(seed=0)
     child = finetune_like(parent, seed=1)
-    res = delta_compression(child, parent, per_param=False, codec="lzma")
+    res = _commit_pair(parent, child, per_param=False, codec="lzma")
     assert res.accepted
     assert res.ratio > 3
 
@@ -219,7 +227,8 @@ def test_pruned_models_preserve_sparsity(tmp_path):
     """G4 regime: quantize-then-delta must keep zeros exactly zero."""
     dense = make_chain_model(seed=0)
     pruned = prune_like(dense, sparsity=0.6)
-    res = delta_compression(pruned, dense, codec="lzma", eps=1e-4)
+    res = _commit_pair(dense, pruned, codec="lzma", eps=1e-4,
+                       root=str(tmp_path))
     for k in pruned.params:
         rec = res.reconstructed.params[k]
         orig_zero = pruned.params[k] == 0
